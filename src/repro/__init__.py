@@ -1,8 +1,3 @@
 """repro: balanced-GEMM training/serving framework (Striking the Balance on TPU)."""
-from repro.compat import ensure_partitionable_rng as _ensure_partitionable_rng
 
 __version__ = "1.0.0"
-
-# Sharding-invariant RNG is assumed throughout (see compat.py); older jax
-# defaults it off.
-_ensure_partitionable_rng()

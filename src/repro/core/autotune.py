@@ -3,8 +3,9 @@
 Wraps the §4.5.2 iterative procedure with a measurement callback and adds a
 generic neighbor-hillclimb refinement (the beyond-paper part): after the
 paper's bk-descent converges, probe the ±1-step neighborhood of the balanced
-plan. On hardware ``measure_fn`` is wall clock; on CPU it defaults to timing
-the XLA fallback (meaningful relative signal) or to the analytical model.
+plan. On hardware ``measure_fn`` is wall clock of the Pallas kernel; off the
+chip the default is the analytical model, and tests time the kernel in
+interpret mode.
 """
 from __future__ import annotations
 
@@ -17,10 +18,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import balance, perfmodel as pm
-from repro.core.context import resolve_hw
+from repro.core.context import current_context, resolve_hw
 from repro.core.plancache import BalanceSnapshot
 from repro.kernels.matmul import LANE, SUBLANE, vmem_bytes
-from repro.kernels.ops import GemmPlan, balanced_matmul
+from repro.kernels.ops import GemmPlan, balanced_matmul, resolve_backend
 
 
 @dataclasses.dataclass
@@ -56,9 +57,21 @@ def model_measure_fn(
 
 def wallclock_measure_fn(
     M: int, K: int, N: int, *, in_dtype=jnp.bfloat16, out_dtype=None,
-    b_layout="row", backend="interpret", repeats=3,
+    b_layout="row", backend: str | None = None, repeats=3,
 ) -> Callable[[GemmPlan], float]:
-    """Wall-clock measurement via the kernel itself (TPU) or interpret mode."""
+    """Wall-clock time of the tiled kernel under one plan.
+
+    ``backend=None`` takes the active context's backend — 'pallas' (the
+    Mosaic kernel) on a TPU; 'interpret' only when a caller asks for it.
+    'xla' is refused: it ignores tile plans, so its times cannot rank them.
+    """
+    ctx = current_context()
+    backend = resolve_backend(backend or ctx.matmul_backend)
+    if backend == "xla":
+        raise ValueError(
+            "plan refinement times the tiled kernel; the xla backend ignores "
+            "tile plans — use --matmul-backend pallas on a TPU")
+    vmem_limit = ctx.hw.vmem_limit_bytes
     rng = np.random.default_rng(0)
 
     def _mk(shape):
@@ -72,7 +85,7 @@ def wallclock_measure_fn(
     def fn(plan: GemmPlan) -> float:
         out = balanced_matmul(
             a, b, plan=plan, out_dtype=out_dtype, b_layout=b_layout,
-            backend=backend,
+            backend=backend, vmem_limit_bytes=vmem_limit,
         )
         jax.block_until_ready(out)
         best = float("inf")
@@ -81,7 +94,7 @@ def wallclock_measure_fn(
             jax.block_until_ready(
                 balanced_matmul(
                     a, b, plan=plan, out_dtype=out_dtype, b_layout=b_layout,
-                    backend=backend,
+                    backend=backend, vmem_limit_bytes=vmem_limit,
                 )
             )
             best = min(best, time.perf_counter() - t0)
@@ -95,7 +108,7 @@ def refine_cached_plans(
     keys: Iterable[tuple] | None = None,
     *,
     measure_factory: Callable[..., Callable[[GemmPlan], float]] | None = None,
-    backend: str = "interpret",
+    backend: str | None = None,
     repeats: int = 2,
     rounds: int = 1,
     resolve: bool = False,
@@ -111,9 +124,11 @@ def refine_cached_plans(
 
     ``measure_factory(M, K, N, in_dtype=…, out_dtype=…, b_layout=…)`` builds
     the per-signature measurement; the default is
-    :func:`wallclock_measure_fn` on ``backend`` (the real kernel on TPU,
-    interpret mode elsewhere). Entries whose key is missing from the cache
-    are skipped — refinement never *adds* signatures.
+    :func:`wallclock_measure_fn` on ``backend``, which defaults to the
+    active context's backend: the real Pallas kernel on a TPU. Interpret
+    mode runs only when a caller passes ``backend='interpret'`` (the CPU
+    tests do). Entries whose key is missing from the cache are skipped —
+    refinement never *adds* signatures.
 
     ``resolve=True`` is the balance auditor's re-solve path: each key is
     first re-solved from the analytic model (``solve_exhaustive``, direct —

@@ -23,7 +23,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.gemm import balanced_gemm
@@ -50,7 +49,7 @@ def output_stationary_gemm(
             a_blk, b_blk, out_dtype=out_dtype, backend=backend, plan=plan
         )
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(m_axis, None), P(None, n_axis)),
@@ -78,7 +77,7 @@ def k_sharded_gemm(
         part = jax.lax.psum(part, k_axis)
         return part.astype(out_dtype or a.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(None, k_axis), P(k_axis, None)),

@@ -131,13 +131,13 @@ def balanced_gemm(
     ``out_scale`` (N,) fuses per-output-channel requantization into the
     kernel epilogue — the quantized-inference path (docs/quantization.md).
     ``backend=None`` resolves to the active context's backend; 'auto' picks
-    pallas on TPU, xla elsewhere.
+    pallas on TPU, xla elsewhere, and 'pallas' off a TPU raises
+    (:func:`repro.kernels.ops.resolve_backend`). The kernels get the scoped
+    VMEM of ``hw`` — the solver's Eq. 5 budget plus Mosaic's headroom.
     """
     ctx = current_context()
-    if backend is None:
-        backend = ctx.matmul_backend
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
+    backend = ops.resolve_backend(
+        ctx.matmul_backend if backend is None else backend)
     hw = resolve_hw(hw)
     *lead, K = a.shape
     M = 1
@@ -166,11 +166,13 @@ def balanced_gemm(
         out = ops.decode_matvec(
             a2, b, bk=plan.bk, bn=plan.bn, out_dtype=out_dtype,
             w_layout=b_layout, backend=backend,
+            vmem_limit_bytes=hw.vmem_limit_bytes,
         )
     else:
         out = ops.balanced_matmul(
             a2, b, bias, plan=plan, out_dtype=out_dtype, b_layout=b_layout,
             activation=activation, out_scale=out_scale, backend=backend,
+            vmem_limit_bytes=hw.vmem_limit_bytes,
         )
     return out.reshape(*lead, N)
 

@@ -9,11 +9,15 @@ instead of baking one chip in, so Table-2-vs-Table-3 style cross-generation
 sweeps are a loop over ``list_hw()``.
 
 Selection precedence: explicit argument > active context > ``REPRO_HW`` env
-var > ``tpu_v5e``.
+var > the attached TPU's ``device_kind`` > ``tpu_v5e``. The last is only a
+modelling default for hosts without a TPU; on a TPU whose kind the registry
+does not know, the default raises instead of describing another chip.
 """
 from __future__ import annotations
 
 import os
+
+import jax
 
 from repro.core.perfmodel import TPU_V5E, HardwareSpec
 
@@ -80,6 +84,31 @@ def list_hw() -> list[str]:
     return sorted(_REGISTRY)
 
 
+# ``jax.Device.device_kind`` of each modelled chip.
+DEVICE_KINDS: dict[str, str] = {
+    "TPU v4": TPU_V4.name,
+    "TPU v5 lite": TPU_V5E.name,
+    "TPU v6 lite": TPU_V6E.name,
+}
+
+
+def hw_for_device_kind(kind: str) -> HardwareSpec:
+    """The spec of a chip by its ``device_kind``; an unmodelled kind raises."""
+    try:
+        return get_hw(DEVICE_KINDS[kind])
+    except KeyError:
+        raise KeyError(
+            f"no hardware spec for device_kind {kind!r} (known: "
+            f"{sorted(DEVICE_KINDS)}); pass --hw to model it as one of "
+            f"{list_hw()}") from None
+
+
 def default_hw() -> HardwareSpec:
-    """Process default: ``REPRO_HW`` env var, else tpu_v5e."""
-    return get_hw(os.environ.get(DEFAULT_HW_ENV, TPU_V5E.name))
+    """Process default: ``REPRO_HW``, else the attached TPU's spec, else
+    tpu_v5e (modelling default off the TPU)."""
+    env = os.environ.get(DEFAULT_HW_ENV)
+    if env:
+        return get_hw(env)
+    if jax.default_backend() == "tpu":
+        return hw_for_device_kind(jax.devices()[0].device_kind)
+    return TPU_V5E

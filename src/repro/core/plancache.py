@@ -25,7 +25,9 @@ from repro.kernels.ops import GemmPlan
 # way that invalidates previously persisted plans.
 # v2: entries carry the solver's balance snapshot (modeled t_comp/t_mem at
 # solve time) so the attribution auditor can detect drift after restarts.
-PLAN_CACHE_VERSION = 2
+# v3: Eq. 5 counts the double-buffered output block and the f32 dot
+# temporary, so v2 plans can overflow the kernels' scoped VMEM.
+PLAN_CACHE_VERSION = 3
 
 PlanKey = tuple  # (hw, M, K, N, in_dtype, out_dtype, b_layout)
 

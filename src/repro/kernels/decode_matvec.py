@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels import ref as _ref
 from repro.kernels.matmul import _acc_dtype
 
@@ -48,7 +47,8 @@ def _gemv_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps, out_dtype, w_layout):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bk", "bn", "out_dtype", "w_layout", "interpret"),
+    static_argnames=("bk", "bn", "out_dtype", "w_layout", "interpret",
+                     "vmem_limit_bytes"),
 )
 def decode_matvec(
     x: jax.Array,
@@ -59,8 +59,11 @@ def decode_matvec(
     out_dtype=None,
     w_layout: str = "row",
     interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
-    """out[B,N] = x[B,K] @ W, W (K,N) row- or (N,K) col-major; B small."""
+    """out[B,N] = x[B,K] @ W, W (K,N) row- or (N,K) col-major; B small.
+
+    ``vmem_limit_bytes`` as in :func:`repro.kernels.matmul.matmul`."""
     if out_dtype is None:
         out_dtype = x.dtype
     B, K = x.shape
@@ -93,8 +96,9 @@ def decode_matvec(
         out_specs=pl.BlockSpec((B, bn), lambda j, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((B, bn), acc)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
     )(x, w)

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels import ref as _ref
 
 # Sublane alignment per dtype (second-to-last dim); lane dim is always 128.
@@ -113,6 +112,7 @@ def _check_divisible(name: str, dim: int, block: int) -> None:
     jax.jit,
     static_argnames=(
         "bm", "bk", "bn", "out_dtype", "b_layout", "activation", "interpret",
+        "vmem_limit_bytes",
     ),
 )
 def matmul(
@@ -128,6 +128,7 @@ def matmul(
     b_layout: str = "row",
     activation: str | None = None,
     interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """C[M,N] = act(A[M,K] @ B * out_scale + bias), B (K,N) row or (N,K) col.
 
@@ -141,6 +142,9 @@ def matmul(
     it, in real f32 units — never pre-scale a bias into the i32 domain.
     Without ``out_scale``, bias is added to the raw accumulator as before.
     Semantics match :func:`repro.kernels.ref.matmul_ref`.
+
+    ``vmem_limit_bytes`` is the scoped VMEM Mosaic may use
+    (``HardwareSpec.vmem_limit_bytes``); None keeps the compiler's default.
     """
     if out_dtype is None:
         out_dtype = a.dtype
@@ -197,8 +201,9 @@ def matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
     )(*args)
@@ -207,14 +212,17 @@ def matmul(
 def vmem_bytes(
     bm: int, bk: int, bn: int, ty_in: int, ty_out: int, acc_bytes: int = 4
 ) -> int:
-    """VMEM working set of one grid step — the TPU Eq. 5 (§4.5.1).
+    """VMEM working set of one grid step — the TPU Eq. 5 (§4.5.1), counted
+    as Mosaic allocates it.
 
-    Double-buffered A and B input blocks (Pallas pipeline), single-buffered
-    accumulator (output-stationary), plus the output block buffer.
+    The Pallas pipeline double-buffers every blocked operand: the A and B
+    input blocks and the output block. The accumulator scratch is single
+    (output-stationary), and ``acc_ref[...] += dot(...)`` materializes the
+    bm x bn dot result in the accumulator dtype before the add.
     """
     return (
         2 * bm * bk * ty_in
         + 2 * bk * bn * ty_in
-        + bm * bn * acc_bytes
-        + bm * bn * ty_out
+        + 2 * bm * bn * ty_out
+        + 2 * bm * bn * acc_bytes
     )

@@ -4,8 +4,10 @@ Responsibilities (the paper's system-level glue, §5.3.1):
 * zero-pad arbitrary (M, K, N) up to the *native GEMM size* — the block-size
   multiples the kernel requires — and slice the result back;
 * pick block sizes from an explicit plan or from the balanced-point defaults;
-* fall back to plain XLA ``dot_general`` on non-TPU backends (the kernels are
-  TPU-targeted; ``interpret=True`` runs them on CPU for tests).
+* resolve the backend: ``xla`` is plain ``dot_general``, ``pallas`` the
+  Mosaic kernel (TPU only — elsewhere it raises rather than lower to
+  something else), ``interpret`` the kernel body run by the Pallas
+  interpreter (CPU tests), ``auto`` pallas on a TPU and xla elsewhere.
 """
 from __future__ import annotations
 
@@ -32,6 +34,19 @@ class GemmPlan:
         """Smallest (M', K', N') multiples of the blocks covering (M, K, N)."""
         r = lambda x, b: -(-x // b) * b
         return r(M, self.bm), r(K, self.bk), r(N, self.bn)
+
+
+def resolve_backend(backend: str) -> str:
+    """The concrete backend for ``backend``; 'pallas' off a TPU raises."""
+    platform = jax.default_backend()
+    if backend == "auto":
+        return "pallas" if platform == "tpu" else "xla"
+    if backend == "pallas" and platform != "tpu":
+        raise ValueError(
+            f"matmul backend 'pallas' compiles Mosaic kernels for a TPU, but "
+            f"JAX's backend is {platform!r}: use 'interpret' to run the "
+            f"kernels here, or 'xla'")
+    return backend
 
 
 def _pad2(x: jax.Array, rows: int, cols: int) -> jax.Array:
@@ -63,17 +78,18 @@ def balanced_matmul(
     activation: str | None = None,
     out_scale: jax.Array | None = None,
     backend: str = "auto",
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """General GEMM through the balanced Pallas kernel with zero-padding.
 
-    backend: 'pallas' | 'interpret' | 'xla' | 'auto' (pallas on TPU else xla).
+    backend: 'pallas' | 'interpret' | 'xla' | 'auto' (:func:`resolve_backend`).
     ``out_scale``: (N,) per-output-channel requantization multiplier, fused
-    into the kernel epilogue (see kernels/matmul.py).
+    into the kernel epilogue (see kernels/matmul.py). ``vmem_limit_bytes``:
+    the kernel's scoped VMEM (``HardwareSpec.vmem_limit_bytes``).
     """
     if out_dtype is None:
         out_dtype = a.dtype
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
+    backend = resolve_backend(backend)
     M, K = a.shape
     N = b.shape[0] if b_layout == "col" else b.shape[1]
     if out_scale is not None:
@@ -116,6 +132,7 @@ def balanced_matmul(
         b_layout=b_layout,
         activation=activation,
         interpret=(backend == "interpret"),
+        vmem_limit_bytes=vmem_limit_bytes,
     )
     if (Mp, Np) != (M, N):
         out = out[:M, :N]
@@ -131,12 +148,12 @@ def decode_matvec(
     out_dtype=None,
     w_layout: str = "row",
     backend: str = "auto",
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """Decode-step skinny GEMM with padding; see decode_matvec.py."""
     if out_dtype is None:
         out_dtype = x.dtype
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
+    backend = resolve_backend(backend)
     if backend == "xla":
         return _ref.gemv_ref(x, w, out_dtype=out_dtype, w_layout=w_layout)
 
@@ -151,7 +168,7 @@ def decode_matvec(
     wp = _pad2(w, Np, Kp) if w_layout == "col" else _pad2(w, Kp, Np)
     out = _mv.decode_matvec(
         xp, wp, bk=bk, bn=bn, out_dtype=out_dtype, w_layout=w_layout,
-        interpret=(backend == "interpret"),
+        interpret=(backend == "interpret"), vmem_limit_bytes=vmem_limit_bytes,
     )
     if (Bp, Np) != (B, N):
         out = out[:B, :N]

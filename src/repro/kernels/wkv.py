@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 CHUNK = 32
 
 
@@ -93,7 +91,7 @@ def wkv(r, k, v, wlog, u, state, *, interpret: bool = False):
             jax.ShapeDtypeStruct((BH, N, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(r, k, v, wlog, u.reshape(BH, 1, N), state)

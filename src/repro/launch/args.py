@@ -7,16 +7,25 @@ selected the same way everywhere:
 
   --hw tpu_v6e --matmul-backend pallas --quantize int8 --plan-cache p.json
 
-``--hw`` defaults to the ``REPRO_HW`` env var (else tpu_v5e); ``--plan-cache
-''`` disables persistence (in-memory cache only).
+``--hw`` defaults to the ``REPRO_HW`` env var, else the attached TPU's spec
+(an unmodelled chip raises), else tpu_v5e as the modelling default off the
+TPU; ``--plan-cache ''`` disables persistence (in-memory cache only).
+
+``use_compile_cache`` is for entry points only: importing a module never
+configures JAX.
 """
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
+
+import jax
 
 from repro.core.context import BACKENDS, GemmContext
 from repro.core.hwregistry import default_hw, list_hw
 from repro.core.plancache import PlanCache, default_cache_path
+from repro.kernels.ops import resolve_backend
 
 
 def add_context_args(
@@ -29,7 +38,8 @@ def add_context_args(
     g.add_argument(
         "--hw", default=None, metavar="GEN",
         help=f"hardware generation for the GEMM planner/perf model "
-             f"({', '.join(list_hw())}; default: $REPRO_HW or tpu_v5e)")
+             f"({', '.join(list_hw())}; default: $REPRO_HW, else the "
+             f"attached TPU, else tpu_v5e)")
     g.add_argument(
         "--matmul-backend", default=backend_default, choices=list(BACKENDS),
         help="kernel backend for every dense()/balanced_gemm")
@@ -194,8 +204,26 @@ def add_serve_engine_args(ap: argparse.ArgumentParser) -> argparse.ArgumentParse
     return ap
 
 
+# <checkout>/.jax_cache: a fixed path, since the path is part of the key.
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, places it and JAX reads it
+    itself; otherwise the cache lives in the checkout's ``.jax_cache``.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
+
+
 def context_from_args(args: argparse.Namespace) -> GemmContext:
     """Build (and load) the execution context an argparse namespace asks for."""
+    resolve_backend(args.matmul_backend)  # 'pallas' off a TPU fails here
     path = args.plan_cache
     if path is None:
         path = default_cache_path()

@@ -12,21 +12,25 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-from repro.compat import make_auto_mesh, mesh_from_devices
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_auto_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_local_mesh(data: int | None = None, model: int = 1) -> Mesh:
-    """Best-effort mesh from whatever devices exist (CPU tests/examples)."""
-    n = len(jax.devices())
-    if data is None:
-        data = n // model
-    devs = np.array(jax.devices()[: data * model]).reshape(data, model)
-    return mesh_from_devices(devs, ("data", "model"))
+def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
+    """A ``data`` x ``model`` mesh over the first ``data * model`` devices.
+
+    The device count is always explicit: a one-chip server stays on one chip
+    however many the host shows.
+    """
+    devices = jax.devices()
+    if data * model > len(devices):
+        raise ValueError(
+            f"a {data}x{model} mesh needs {data * model} devices; "
+            f"{len(devices)} visible")
+    devs = np.array(devices[: data * model]).reshape(data, model)
+    return Mesh(devs, ("data", "model"))

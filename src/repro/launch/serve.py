@@ -8,9 +8,11 @@ Start-up follows the production recipe the GemmContext subsystem enables:
 
 1. build the execution context from the shared --hw/--matmul-backend/
    --quantize arg layer, loading previously solved plans from the
-   persistent cache;
-2. with --quantize int8, quantize the parameter tree *once at load*
-   (quant.prequant) so decode streams int8 weights — not the in-graph
+   persistent cache; keep JAX's compilation cache in
+   ``$JAX_COMPILATION_CACHE_DIR`` or the checkout (``args.use_compile_cache``);
+2. make the seeded weights on the mesh in one jit (``init_params``), in
+   the activation dtype; with --quantize int8 the same program quantizes
+   them (quant.prequant) so decode streams int8 weights — not the in-graph
    re-quantization demo path;
 3. warm up: ``plan_model`` pre-solves every GEMM signature the model will
    issue (prefill + decode, all projections) and persists them, so steady-
@@ -42,6 +44,7 @@ batched pass, rejected tails rewind in place. See docs/serving.md.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -53,10 +56,35 @@ from repro import models
 from repro.core.context import use_context
 from repro.core.gemm import plan_model
 from repro.launch.args import (add_context_args, add_serve_engine_args,
-                               context_from_args)
+                               context_from_args, use_compile_cache)
 from repro.launch.mesh import make_local_mesh, make_production_mesh
+from repro.parallel import sharding as shd
 from repro.quant import prequant
 from repro.train.servestep import make_serve_step
+
+
+def init_params(cfg, mesh, *, quantize: bool = False, seed: int = 0):
+    """Seeded random serving weights, made on ``mesh`` by one jit.
+
+    Serving holds weights in the activation dtype (bf16 for the published
+    configs — their checkpoints' dtype; training keeps f32 masters), and
+    the jit's out_shardings place each leaf where the partitioner wants it,
+    so no f32 copy of the model exists on the host or the device. With
+    ``quantize`` the same program quantizes every projection
+    (quant.prequant): the float tree never lives beside the int8 one.
+    Returns ``(params, param_axes)``.
+    """
+    cfg = dataclasses.replace(cfg, param_dtype=cfg.activation_dtype)
+    axes = models.axes(cfg)
+    if quantize:
+        axes = prequant.quantize_axes(axes)
+
+    def make():
+        params = models.init(jax.random.PRNGKey(seed), cfg)
+        return prequant.quantize_params(params) if quantize else params
+
+    shardings = shd.param_shardings(axes, jax.eval_shape(make), mesh)
+    return jax.jit(make, out_shardings=shardings)(), axes
 
 
 def serve_batch(cfg, mesh, params, prompts, *, gen_len: int, max_len: int,
@@ -180,7 +208,7 @@ def _report_attrib(ctx, engine, m, *, rebalance: bool) -> None:
           + (f", persisted to {saved}" if saved else ""))
 
 
-def _run_engine(args, ctx, cfg, mesh, params, param_axes) -> None:
+def _run_engine(args, ctx, cfg, mesh, params, param_axes):
     """--engine: continuous batching over a mixed-length synthetic trace
     (with --prefix-cache: a shared-header trace, so the radix cache has
     prefixes to dedupe; with --bursty-trace: bursts of mixed-priority
@@ -209,13 +237,9 @@ def _run_engine(args, ctx, cfg, mesh, params, param_axes) -> None:
         dcfg = C.get_config(args.spec_draft_config)
         if args.smoke:
             dcfg = C.smoke(dcfg)
-        dparams = models.init(jax.random.PRNGKey(0), dcfg)
-        daxes, dquant = None, None
-        if args.spec_draft_quantize == "int8":
-            # same once-at-load prequant recipe as the target's --quantize
-            dparams = prequant.quantize_params(dparams)
-            daxes = prequant.quantize_axes(models.axes(dcfg))
-            dquant = "int8"
+        # same once-at-load prequant recipe as the target's --quantize
+        dquant = "int8" if args.spec_draft_quantize == "int8" else None
+        dparams, daxes = init_params(dcfg, mesh, quantize=dquant == "int8")
         spec_kwargs = dict(
             spec_draft_cfg=dcfg, spec_draft_params=dparams,
             spec_k=args.spec_k, spec_draft_param_axes=daxes,
@@ -383,9 +407,13 @@ def _run_engine(args, ctx, cfg, mesh, params, param_axes) -> None:
                   f"exposition written to {prom_path}")
     # steady state needs no guard here: a warmed engine's run() itself
     # raises PlanCacheColdError on any lazy solve or unseen signature
+    return m
 
 
-def main():
+def main(argv: list[str] | None = None):
+    """CLI entry point; ``argv`` defaults to ``sys.argv[1:]``. Returns the
+    engine's :class:`repro.serve.EngineMetrics` with ``--engine``, else
+    None."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-4b")
     ap.add_argument("--smoke", action="store_true")
@@ -397,8 +425,9 @@ def main():
                     help="skip the plan pre-solve (plans solve lazily)")
     add_context_args(ap)
     add_serve_engine_args(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    use_compile_cache()
     ctx = context_from_args(args)
     with use_context(ctx):
         cfg = C.get_config(args.arch)
@@ -407,17 +436,13 @@ def main():
         mesh = (make_production_mesh() if args.production_mesh
                 else make_local_mesh())
 
-        params = models.init(jax.random.PRNGKey(0), cfg)
-        param_axes = None
-        if ctx.quant_mode == "int8":
-            # quantize once at load: decode streams int8 weights, the
-            # dequantize rides the GEMM epilogue (§5.1 traffic win)
-            params = prequant.quantize_params(params)
-            param_axes = prequant.quantize_axes(models.axes(cfg))
+        # int8: quantized once at load — decode streams int8 weights, the
+        # dequantize rides the GEMM epilogue (§5.1 traffic win)
+        params, param_axes = init_params(
+            cfg, mesh, quantize=ctx.quant_mode == "int8")
 
         if args.engine:
-            _run_engine(args, ctx, cfg, mesh, params, param_axes)
-            return
+            return _run_engine(args, ctx, cfg, mesh, params, param_axes)
 
         rng = np.random.default_rng(0)
         prompts = jnp.asarray(

@@ -28,7 +28,8 @@ from repro.data.synthetic import batch_for
 from repro.ft import checkpoint as ckpt_lib
 from repro.ft.elastic import resume_on_mesh
 from repro.ft.straggler import StragglerMonitor
-from repro.launch.args import add_context_args, context_from_args
+from repro.launch.args import (add_context_args, context_from_args,
+                               use_compile_cache)
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 
 
@@ -47,6 +48,7 @@ def main():
     add_context_args(ap, include_quant=False)
     args = ap.parse_args()
 
+    use_compile_cache()
     with use_context(context_from_args(args)):
         return _run(args)
 

@@ -101,8 +101,6 @@ def embed_lookup(table: jax.Array, ids: jax.Array, mesh=None) -> jax.Array:
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     if mesh is None or "model" not in getattr(mesh, "axis_names", ()):
         return jnp.take(table, ids, axis=0)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -128,7 +126,7 @@ def embed_lookup(table: jax.Array, ids: jax.Array, mesh=None) -> jax.Array:
         return jax.lax.psum(rows, "model")
 
     ids_spec = P(dp_spec, *([None] * (ids.ndim - 1)))
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("model", None), ids_spec),
         out_specs=P(dp_spec, *([None] * ids.ndim)),

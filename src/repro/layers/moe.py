@@ -21,7 +21,6 @@ from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.layers import common as cm
@@ -145,8 +144,7 @@ def moe_ffn(
     if mesh is None:
         import numpy as _np
 
-        from repro.compat import mesh_from_devices
-        mesh = mesh_from_devices(
+        mesh = Mesh(
             _np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
     E = p.w_router.shape[1]
     mesh_axes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -273,7 +271,7 @@ def moe_ffn(
     tp_ax = tp_axis if tp_axis else None
     tm = (jnp.ones(x.shape[:2], bool) if token_mask is None
           else jnp.broadcast_to(token_mask.astype(bool), x.shape[:2]))
-    out = shard_map(
+    out = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

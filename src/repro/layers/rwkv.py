@@ -156,14 +156,14 @@ def wkv_chunk_parallel(r, k, v, wlog, u, state, chunk: int = 32):
         # nor underflows f32 for any realistic decay spectrum.
         mid = cl[..., C // 2, :][..., None, :]
         rDm = rc * jnp.exp(cl - mid)
-        kinv = kc * jnp.exp(jnp.clip(mid - (cl + wlc), a_max=60.0))
+        kinv = kc * jnp.exp(jnp.clip(mid - (cl + wlc), max=60.0))
         A = jnp.einsum("bhtn,bhsn->bhts", rDm, kinv)
         A = A * causal
         diag = jnp.sum(rc * u_bh * kc, axis=-1)   # bonus term (B,H,C)
         y2 = jnp.einsum("bhts,bhsm->bhtm", A, vc) + diag[..., None] * vc
         # state update
         kdec = kc * jnp.exp(
-            jnp.clip(ce[..., None, :] - (cl + wlc), a_max=0.0))
+            jnp.clip(ce[..., None, :] - (cl + wlc), max=0.0))
         S_new = jnp.exp(ce)[..., :, None] * S + jnp.einsum(
             "bhsn,bhsm->bhnm", kdec, vc)
         return S_new, y1 + y2

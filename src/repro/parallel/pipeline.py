@@ -22,7 +22,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -89,7 +88,7 @@ def pipeline_apply(
         return P(axis, *([None] * (p.ndim - 1)))
 
     pspec = jax.tree.map(leaf_spec, stage_params)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(pspec, P(*([None] * x.ndim))),

@@ -1,7 +1,7 @@
 """Properties of the analytical model and the balanced-point solvers."""
 import jax.numpy as jnp
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import balance, perfmodel as pm
 from repro.core.tiling import TileConfig

@@ -38,6 +38,30 @@ def test_env_driven_default(monkeypatch):
     assert hwregistry.default_hw().name == "tpu_v5e"
 
 
+def test_device_kind_resolves_spec_and_unknown_tpu_raises(monkeypatch):
+    assert hwregistry.hw_for_device_kind("TPU v5 lite").name == "tpu_v5e"
+    assert hwregistry.hw_for_device_kind("TPU v4").name == "tpu_v4"
+    assert hwregistry.hw_for_device_kind("TPU v6 lite").name == "tpu_v6e"
+
+    class _Chip:
+        device_kind = "TPU v7 unknown"
+
+    # on a TPU the default follows the attached chip, never tpu_v5e
+    monkeypatch.delenv(hwregistry.DEFAULT_HW_ENV, raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Chip()])
+    with pytest.raises(KeyError, match="TPU v7 unknown"):
+        hwregistry.default_hw()
+    _Chip.device_kind = "TPU v5 lite"
+    assert hwregistry.default_hw().name == "tpu_v5e"
+
+
+def test_kernel_vmem_limit_covers_solver_budget():
+    for name in hwregistry.list_hw():
+        hw = hwregistry.get_hw(name)
+        assert hw.vmem_limit_bytes > hw.vmem_bytes
+
+
 # ------------------------------------------------------ context isolation
 def test_use_context_nested_isolation():
     base_hw = current_context().hw.name

@@ -4,7 +4,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro import configs as C
 from repro import models

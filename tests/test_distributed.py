@@ -21,13 +21,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np
 import jax, jax.numpy as jnp
-from repro.compat import mesh_from_devices
+from jax.sharding import Mesh
 import sys
 
 results = {}
 
 devs = np.array(jax.devices()).reshape(4, 2)
-mesh = mesh_from_devices(devs, ("data", "model"))
+mesh = Mesh(devs, ("data", "model"))
 
 # ---- 1. output-stationary distributed GEMM == local matmul
 from repro.core.distributed import output_stationary_gemm, k_sharded_gemm
@@ -76,8 +76,7 @@ from repro.train.trainstep import make_train_step
 from repro.data.synthetic import batch_for
 cfg = C.smoke(C.get_config("internlm2-20b"))
 art = make_train_step(cfg, mesh)
-mesh1 = mesh_from_devices(np.array(jax.devices()[:1]).reshape(1, 1),
-                          ("data", "model"))
+mesh1 = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
 art1 = make_train_step(cfg, mesh1)
 b = {k: jnp.asarray(v) for k, v in batch_for(cfg, 32, 8, 0).items()}
 with mesh:

@@ -3,7 +3,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import autotune, balance
 from repro.ft.straggler import StragglerMonitor, StragglerConfig
@@ -163,8 +163,8 @@ def test_refine_cached_plans_keeps_measured_best():
 
 
 def test_refine_cached_plans_wallclock_smoke():
-    """The default wall-clock measure path runs end-to-end on a tiny
-    signature (interpret-mode kernel timing)."""
+    """The wall-clock measure path runs end-to-end on a tiny signature
+    (interpret-mode kernel timing, asked for explicitly off the chip)."""
     from repro.core.gemm import plan_for
     from repro.core.plancache import PlanCache
     from repro.core.context import use_context
@@ -173,6 +173,7 @@ def test_refine_cached_plans_wallclock_smoke():
     with use_context(plan_cache=cache):
         with cache.warmup():
             plan_for(32, 256, 128, in_dtype=jnp.float32)
-        stats = autotune.refine_cached_plans(cache, repeats=1)
+        stats = autotune.refine_cached_plans(
+            cache, repeats=1, backend="interpret")
     assert stats["measured"] >= 1
     assert stats["refined"] + stats["kept"] == 1

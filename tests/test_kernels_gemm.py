@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.matmul import vmem_bytes
@@ -176,6 +176,16 @@ def test_property_vmem_model_positive_and_monotone(bm, bk, bn):
     assert vmem_bytes(2 * bm, bk, bn, 2, 2) > v
     assert vmem_bytes(bm, 2 * bk, bn, 2, 2) > v
     assert vmem_bytes(bm, bk, 2 * bn, 2, 2) > v
+
+
+def test_pallas_backend_refuses_a_non_tpu_platform():
+    a = _rand((64, 128), jnp.bfloat16)
+    b = _rand((128, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match="TPU"):
+        ops.balanced_matmul(a, b, backend="pallas")
+    with pytest.raises(ValueError, match="TPU"):
+        ops.decode_matvec(a[:8], b, backend="pallas")
+    assert ops.resolve_backend("auto") == "xla"
 
 
 def test_xla_fallback_matches_oracle():

@@ -373,3 +373,44 @@ def test_synthetic_trace_shapes():
     assert [r.max_new_tokens for r in tr] == [2, 3, 2, 3, 2]
     assert all(r.stop_ids == (1,) for r in tr)
     assert all(r.prompt.max() < 100 for r in tr)
+
+
+# ------------------------------------------------------------ launcher
+def test_init_params_in_activation_dtype_and_quantized_in_place():
+    """Serving weights are born in the activation dtype (f32 masters are
+    training's); with quantize the same program emits int8 projections."""
+    import dataclasses
+
+    from repro.launch.serve import init_params
+    from repro.quant.int8 import QuantizedLinear
+
+    cfg = dataclasses.replace(C.smoke(C.get_config("qwen1.5-4b")),
+                              activation_dtype="bfloat16")
+    mesh = make_local_mesh()
+    params, axes = init_params(cfg, mesh)
+    assert {x.dtype for x in jax.tree.leaves(params)} == {jnp.dtype("bfloat16")}
+    assert jax.tree.structure(axes, is_leaf=models.lm.is_axes_leaf) \
+        .num_leaves == len(jax.tree.leaves(params))
+    qparams, _ = init_params(cfg, mesh, quantize=True)
+    wq = qparams["layers"]["mlp"].w_in
+    assert isinstance(wq, QuantizedLinear) and wq.w_q.dtype == jnp.int8
+    assert qparams["unembed"].dtype == jnp.bfloat16
+
+
+def test_compile_cache_dir_from_env_or_checkout(monkeypatch):
+    import pathlib
+
+    from repro.launch.args import COMPILE_CACHE_DIR, use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert use_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before  # left to JAX
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == str(COMPILE_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(COMPILE_CACHE_DIR)
+        checkout = pathlib.Path(__file__).resolve().parents[1]
+        assert COMPILE_CACHE_DIR == checkout / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -93,7 +93,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import mesh_from_devices
+from jax.sharding import Mesh
 from repro import configs as C
 from repro.train import trainstep
 from repro.roofline import hlo as H
@@ -101,8 +101,7 @@ from repro.launch.dryrun import _with_shardings, input_specs
 from repro.configs.base import ShapeConfig
 
 cfg = C.smoke(C.get_config("olmoe-1b-7b"))
-mesh = mesh_from_devices(np.array(jax.devices()).reshape(4, 2),
-                         ("data", "model"))
+mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
 art = trainstep.make_train_step(cfg, mesh, global_batch=8, seq_len=32)
 state_in = _with_shardings(art.state_shapes, art.state_shardings)
 shape = ShapeConfig("t", 32, 8, "train")
