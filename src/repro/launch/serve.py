@@ -389,8 +389,6 @@ def _run_engine(args, ctx, cfg, mesh, params, param_axes):
         t = m.timing
         print(f"[trace] {len(obj['traceEvents'])} events "
               f"({t.get('events_dropped', 0)} dropped) -> {args.trace_out}; "
-              f"host {t.get('host_s', 0.0):.3f}s / device "
-              f"{t.get('device_s', 0.0):.3f}s across "
               f"{len(t.get('phases', {}))} phases")
         _report_attrib(ctx, engine, m, rebalance=args.rebalance_drifted)
     elif args.rebalance_drifted:

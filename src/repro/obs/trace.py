@@ -3,10 +3,11 @@
 The tracer records two families of events (phase glossary in
 docs/observability.md):
 
-* **Phase spans** — how a tick's wall time decomposes: ``admit``,
-  ``bind``, ``prefill-chunk``, ``spec-draft``, ``spec-verify``,
-  ``decode``, ``sample``, ``expire``, ``reclaim``.  Each span carries a
-  host timestamp pair (``clock()`` at enter/exit, default
+* **Phase spans** — how a tick's wall time decomposes on the host:
+  ``tick`` around the whole of it, and inside it ``expire``, ``bind``,
+  ``prefill-chunk``, ``decode``, ``wait``, ``fetch``, ``sample``,
+  ``account`` and the rest of :data:`PHASES`.  Each span carries a host
+  timestamp pair (``clock()`` at enter/exit, default
   ``time.perf_counter``) and the engine tick it ran under.
 * **Request lifecycle events** — what one request experienced:
   ``submit``, ``admit``, ``chunk``, ``first-token``, ``preempt``,
@@ -16,12 +17,19 @@ docs/observability.md):
 
 Design constraints (why it looks the way it does):
 
-* **Zero cost when off.**  The engine holds :data:`NULL_TRACER` unless
-  the caller passes a real :class:`Tracer`.  Every ``NullTracer`` method
-  returns immediately and never reads a clock, so an untraced run makes
-  exactly the same clock-read sequence as a build without tracing —
-  this matters under ``SimClock``, where *reading* the engine clock
-  advances it.
+* **On the profiler's clock.**  While a JAX profiler session runs
+  (``jax.profiler.start_trace`` / ``jax.profiler.trace``), every phase
+  span also enters a ``jax.profiler.TraceAnnotation("serve.<phase>",
+  tick=...)``, so the span lands on the host plane of the profiler
+  trace, on the same clock as the device's ops.  The tracer asks the
+  profiler once per tick, in :meth:`Tracer.set_tick`.
+* **Zero cost when off.**  An engine given no tracer writes to
+  :data:`PROCESS_TRACER`, which records only while a profiler session
+  runs: with none, ``phase()`` returns the shared no-op span and no
+  method reads a clock.  :data:`NULL_TRACER` never records at all.  So
+  an untraced run makes exactly the same clock-read sequence as a build
+  without tracing — this matters under ``SimClock``, where *reading*
+  the engine clock advances it.
 * **The tracer never reads the engine clock.**  All tracer timestamps
   come from its own injected ``clock`` (host ``perf_counter`` by
   default); engine-time ordering is carried by the integer ``tick``
@@ -37,24 +45,34 @@ from __future__ import annotations
 import json
 import time
 from collections import deque
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-# Phase glossary: name -> where the time goes.  "device" phases are
-# dominated by dispatched computation (the engine syncs inside decode /
-# spec phases; prefill/admit spans cover dispatch of the traced step),
-# "host" phases are pure Python bookkeeping.
+# Phase glossary: span name -> what it covers.  Every span is host time;
+# the device's time is read from the profiler trace's device plane, which
+# the spans share a clock with while a profiler session runs.  ``wait``
+# and ``fetch`` nest inside ``decode`` (and ``admit``); every other span
+# of a tick nests directly inside ``tick``.
 PHASES: dict[str, str] = {
-    "admit": "device",          # one-shot / draft admission prefill
-    "bind": "host",             # paged slot binding + block alloc
-    "prefill-chunk": "device",  # one chunked-prefill step
-    "spec-draft": "device",     # draft chain proposing k tokens
-    "spec-verify": "device",    # batched (slots, k+1) target verify
-    "decode": "device",         # masked batched decode step
-    "sample": "host",           # host-side token accept/append loop
-    "expire": "host",           # deadline expiry sweep
-    "reclaim": "host",          # prefix-cache LRU block reclaim
+    "tick": "one whole engine tick",
+    "expire": "deadline expiry sweep",
+    "bind": "paged admission: slot binding and block allocation",
+    "admit": "one-shot admission prefill (contiguous engine): dispatch, "
+             "wait and fetch; or a draft-model admission's dispatch",
+    "prefill-chunk": "dispatch of one chunked-prefill step",
+    "decode": "dispatch of the masked batched decode step, then its "
+              "wait and fetch",
+    "wait": "the host blocked until a step's logits exist on the device",
+    "fetch": "the copy of a step's logits to the host, as far as it is "
+             "left once the wait ends",
+    "sample": "host sampling / acceptance and the token append loop",
+    "account": "block-pool and metrics bookkeeping, registry publish",
+    "spec-draft": "draft chain proposing k tokens, to proposals on the host",
+    "spec-verify": "batched (slots, k+1) target verify, to logits on the "
+                   "host",
+    "reclaim": "prefix-cache LRU block reclaim",
 }
 
 REQUEST_EVENTS = (
@@ -71,10 +89,20 @@ _PID_PHASES = 1
 _PID_REQUESTS = 2
 
 
-class _PhaseSpan:
-    """Context manager recording one phase span on ``__exit__``."""
+class Span(NamedTuple):
+    """One recorded phase span, as :meth:`Tracer.spans` returns it."""
 
-    __slots__ = ("_tr", "name", "tick", "slot", "args", "_t0")
+    name: str
+    start: float    # the tracer's clock (time.perf_counter by default), s
+    end: float
+    tick: int
+
+
+class _PhaseSpan:
+    """Context manager recording one phase span on ``__exit__``; while a
+    profiler session runs it also enters a ``serve.<name>`` annotation."""
+
+    __slots__ = ("_tr", "name", "tick", "slot", "args", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, tick: int, slot, args):
         self._tr = tr
@@ -83,25 +111,56 @@ class _PhaseSpan:
         self.slot = slot
         self.args = args
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        if self._tr._annotate:
+            self._ann = TraceAnnotation("serve." + self.name, tick=self.tick)
+            self._ann.__enter__()
         self._t0 = self._tr._clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self._tr._end_phase(self)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
+class _NullSpan:
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+
+_NULL_SPAN = _NullSpan()
+
+
 class Tracer:
-    """Ring-buffer event recorder with Chrome trace-event export."""
+    """Ring-buffer event recorder with Chrome trace-event export.
+
+    ``profiled_only=True`` makes a recorder that keeps spans and events
+    only while a profiler session runs (:data:`PROCESS_TRACER`); a
+    default tracer records always.  Either writes its phase spans into
+    the profiler trace while a session runs.
+    """
 
     enabled = True
 
     def __init__(self, ring_events: int = 65536,
-                 clock: Callable[[], float] = time.perf_counter):
+                 clock: Callable[[], float] = time.perf_counter, *,
+                 profiled_only: bool = False):
         self._clock = clock
         self._ring_events = int(ring_events)
+        self._profiled_only = profiled_only
+        # refreshed once per tick by set_tick: whether a profiler session
+        # runs (spans annotate the profiler trace), and whether to record
+        self._annotate = False
+        self._record = not profiled_only
         self.reset()
 
     # -- recording ---------------------------------------------------
@@ -122,24 +181,30 @@ class Tracer:
         """Default engine tick stamped on events that don't pass one.
 
         The engine calls this once per tick so deep callees (e.g. the
-        prefix cache's reclaimer) don't need tick plumbing.
+        prefix cache's reclaimer) don't need tick plumbing.  It is also
+        where the tracer asks whether a profiler session runs.
         """
         self._tick = tick
+        self._annotate = TraceAnnotation.is_enabled()
+        if self._profiled_only:
+            self._record = self._annotate
 
     @property
     def tick(self) -> int:
         return self._tick
 
-    def phase(self, name: str, slot: int | None = None, **args) -> _PhaseSpan:
+    def phase(self, name: str, slot: int | None = None, **args):
         """Span context manager: ``with tr.phase("decode"): ...``."""
+        if not self._record:
+            return _NULL_SPAN
         return _PhaseSpan(self, name, self._tick, slot, args or None)
 
     def _end_phase(self, span: _PhaseSpan) -> None:
         t1 = self._clock()
         self._push({
             "kind": "phase", "name": span.name, "tick": span.tick,
-            "slot": span.slot, "ts": span._t0 - self._t0,
-            "dur": t1 - span._t0, "args": span.args,
+            "slot": span.slot, "ts": span._t0, "end": t1,
+            "args": span.args,
         })
         self._durations.setdefault(span.name, []).append(t1 - span._t0)
 
@@ -153,19 +218,30 @@ class Tracer:
         *same* stamps that feed ``SpecStats.draft_s``/``verify_s`` and
         the two reconcile exactly.
         """
+        if not self._record:
+            return
         self._push({
             "kind": "phase", "name": name, "tick": self._tick,
-            "slot": slot, "ts": t_start - self._t0,
-            "dur": t_end - t_start, "args": args or None,
+            "slot": slot, "ts": t_start, "end": t_end, "args": args or None,
         })
         self._durations.setdefault(name, []).append(t_end - t_start)
 
+    def spans(self, name: str, t_start: float, t_end: float) -> list[Span]:
+        """The phase spans called ``name`` in the ring that lie within
+        [``t_start``, ``t_end``] on the tracer's clock, in the order they
+        ended."""
+        return [Span(r["name"], r["ts"], r["end"], r["tick"])
+                for r in self.events
+                if r["kind"] == "phase" and r["name"] == name
+                and t_start <= r["ts"] and r["end"] <= t_end]
+
     def instant(self, name: str, **args) -> None:
         """Point-in-time marker on the engine track (e.g. plan events)."""
+        if not self._record:
+            return
         self._push({
             "kind": "instant", "name": name, "tick": self._tick,
-            "slot": None, "ts": self._clock() - self._t0,
-            "args": args or None,
+            "slot": None, "ts": self._clock(), "args": args or None,
         })
 
     def counter(self, name: str, values: dict) -> None:
@@ -175,9 +251,11 @@ class Tracer:
         same ``name`` render as stacked counter tracks (e.g. attributed
         device seconds by bound class, pool blocks in use).
         """
+        if not self._record:
+            return
         self._push({
             "kind": "counter", "name": name, "tick": self._tick,
-            "slot": None, "ts": self._clock() - self._t0,
+            "slot": None, "ts": self._clock(),
             "args": {k: float(v) for k, v in values.items()},
         })
 
@@ -189,7 +267,9 @@ class Tracer:
         inner "active" span, so a preempted request shows a gap between
         its active segments.  Everything else is an async instant.
         """
-        ts = self._clock() - self._t0
+        if not self._record:
+            return
+        ts = self._clock()
         rec = {
             "kind": "request", "event": event, "req": request_id,
             "tick": self._tick, "ts": ts, "args": args or None,
@@ -222,7 +302,7 @@ class Tracer:
         return self._durations
 
     def phase_summary(self) -> dict:
-        """The metrics ``timing`` section: per-phase stats + host/device split.
+        """The metrics ``timing`` section: per-phase stats.
 
         Percentiles are ``np.percentile`` over the complete duration
         list, so they are an exact, deterministic function of the
@@ -230,28 +310,17 @@ class Tracer:
         deterministic tests).
         """
         phases = {}
-        host_s = 0.0
-        device_s = 0.0
         for name in sorted(self._durations):
             durs = np.asarray(self._durations[name], dtype=np.float64)
-            kind = PHASES.get(name, "host")
-            total = float(durs.sum())
             phases[name] = {
-                "kind": kind,
                 "count": int(durs.size),
-                "total_s": total,
+                "total_s": float(durs.sum()),
                 "mean_s": float(durs.mean()),
                 "p50_s": float(np.percentile(durs, 50)),
                 "p99_s": float(np.percentile(durs, 99)),
             }
-            if kind == "device":
-                device_s += total
-            else:
-                host_s += total
         return {
             "phases": phases,
-            "host_s": host_s,
-            "device_s": device_s,
             "events_recorded": self._n_events,
             "events_dropped": self.events_dropped,
         }
@@ -285,6 +354,7 @@ class Tracer:
             out.append(ev)
 
         for rec in self.events:
+            ts = rec["ts"] - self._t0     # seconds since reset
             args = dict(rec.get("args") or {})
             if rec["tick"] is not None:
                 args["tick"] = rec["tick"]
@@ -293,14 +363,15 @@ class Tracer:
                 tids_seen.add(tid)
                 out.append({
                     "name": rec["name"], "cat": "phase", "ph": "X",
-                    "ts": rec["ts"] * _US, "dur": max(rec["dur"], 0.0) * _US,
+                    "ts": ts * _US,
+                    "dur": max(rec["end"] - rec["ts"], 0.0) * _US,
                     "pid": _PID_PHASES, "tid": tid, "args": args,
                 })
             elif rec["kind"] == "instant":
                 tids_seen.add(0)
                 out.append({
                     "name": rec["name"], "cat": "engine", "ph": "i",
-                    "s": "t", "ts": rec["ts"] * _US,
+                    "s": "t", "ts": ts * _US,
                     "pid": _PID_PHASES, "tid": 0, "args": args,
                 })
             elif rec["kind"] == "counter":
@@ -308,12 +379,12 @@ class Tracer:
                 tids_seen.add(0)
                 out.append({
                     "name": rec["name"], "cat": "counter", "ph": "C",
-                    "ts": rec["ts"] * _US,
+                    "ts": ts * _US,
                     "pid": _PID_PHASES, "tid": 0,
                     "args": dict(rec.get("args") or {}),
                 })
             else:  # request lifecycle
-                event, req, ts = rec["event"], rec["req"], rec["ts"]
+                event, req = rec["event"], rec["req"]
                 if event == "submit":
                     async_ev("b", "request", req, ts, args)
                     outer_open[req] = ts
@@ -383,16 +454,7 @@ class NullTracer:
 
     enabled = False
 
-    class _NullSpan:
-        __slots__ = ()
-
-        def __enter__(self):
-            return self
-
-        def __exit__(self, exc_type, exc, tb):
-            return False
-
-    _SPAN = _NullSpan()
+    _SPAN = _NULL_SPAN
 
     def reset(self) -> None:
         pass
@@ -423,6 +485,11 @@ class NullTracer:
 
 
 NULL_TRACER = NullTracer()
+
+# The process-wide recorder: what an engine given no tracer writes to.  It
+# records only while a profiler session runs, so the benchmark's traced
+# stretch can read it (``spans``) with no tracer passed in.
+PROCESS_TRACER = Tracer(profiled_only=True)
 
 
 def validate_chrome_trace(obj, require_phases=(), min_requests: int = 0,

@@ -61,7 +61,7 @@ from repro.configs.base import ModelConfig
 from repro.core.context import current_context
 from repro.obs.attrib import AttributionLedger
 from repro.obs.registry import Registry, prom_name
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import PROCESS_TRACER
 from repro.quant.kvcache import KVCacheDtype, kv_block_bytes
 from repro.serve.blockpool import BlockPool
 from repro.serve.metrics import EngineMetrics
@@ -139,8 +139,12 @@ class ServeEngine:
         # observability (repro.obs): the tracer keeps its own host clock
         # and never reads self._now — under SimClock a clock *read*
         # advances time, so tracing on/off must not change the engine's
-        # read sequence. NULL_TRACER makes every hook a no-op.
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        # read sequence. A caller's tracer records the whole run and
+        # feeds the metrics' timing and attribution sections; without
+        # one the engine writes to the process-wide tracer, which records
+        # only while a profiler session runs and is never reset here.
+        self.tracer = tracer if tracer is not None else PROCESS_TRACER
+        self._traced_run = tracer is not None and tracer.enabled
         self.registry = registry if registry is not None else Registry()
         self.metrics_interval_ticks = metrics_interval_ticks
         # balance auditor: phase→signature profiles are captured during
@@ -259,7 +263,8 @@ class ServeEngine:
         """Fresh scheduler/state/metrics; compiled functions are kept (the
         benchmark times a second run to measure steady state, not XLA)."""
         ctx = current_context()
-        self.tracer.reset()
+        if self._traced_run:
+            self.tracer.reset()
         self.attrib.reset_run()
         # the engine's time base: every stamp (submit, admission, TTFT,
         # deadlines, trace arrival_s) is seconds since this reset, so
@@ -460,17 +465,31 @@ class ServeEngine:
         the next token of the stream) — only a genuinely first token
         feeds the budget controller's TTFT loop."""
         first_ever = st.first_token_s is None
-        tok = self._sample(logits, st)
-        st.append(tok, now, tick=self.sched.tick)
-        if first_ever:
-            self.budget.observe_ttft(now - st.request.submitted_s)
-            self.tracer.request_event("first-token", st.request.request_id,
-                                      slot=st.slot)
-        self._next_tok[st.slot] = tok
-        reason = ("length" if len(st.tokens) >= self._budget(st)
-                  else st.should_stop())
-        if reason:
-            self._finish(st, reason, now)
+        with self.tracer.phase("sample", slot=st.slot, n=1):
+            tok = self._sample(logits, st)
+            st.append(tok, now, tick=self.sched.tick)
+            if first_ever:
+                self.budget.observe_ttft(now - st.request.submitted_s)
+                self.tracer.request_event("first-token",
+                                          st.request.request_id,
+                                          slot=st.slot)
+            self._next_tok[st.slot] = tok
+            reason = ("length" if len(st.tokens) >= self._budget(st)
+                      else st.should_stop())
+            if reason:
+                self._finish(st, reason, now)
+
+    def _fetch(self, logits) -> np.ndarray:
+        """A step's logits on the host: block until the device has them
+        (the ``wait`` span), then copy them over (``fetch``)."""
+        # start the copy first, so it follows the step the moment the
+        # device finishes (as a bare np.asarray would) rather than after
+        # the host has woken from the wait
+        logits.copy_to_host_async()
+        with self.tracer.phase("wait"):
+            jax.block_until_ready(logits)
+        with self.tracer.phase("fetch"):
+            return np.asarray(logits)
 
     def _admit_all(self, now: float) -> int:
         """Contiguous path: drain the queue into free lanes; each admission
@@ -491,7 +510,7 @@ class ServeEngine:
                     self.params, self.state, jnp.asarray(prompt),
                     jnp.asarray(st.slot, jnp.int32),
                     jnp.asarray(req.prompt_len, jnp.int32))
-                np_logits = np.asarray(logits)
+                np_logits = self._fetch(logits)
             self._first_token(st, np_logits, self._rel_now())
 
     def _bind_admissions(self, now: float) -> int:
@@ -565,7 +584,7 @@ class ServeEngine:
                                   slot=st.slot, n=n, done=st.prefill_done)
         if st.prefilling:
             return 0
-        self._first_token(st, np.asarray(logits), self._rel_now())
+        self._first_token(st, self._fetch(logits), self._rel_now())
         return 1
 
     def _spec_round(self, mask: np.ndarray) -> int:
@@ -604,7 +623,7 @@ class ServeEngine:
         t2 = time.perf_counter()
         self.spec_stats.draft_s += t1 - t0
         self.spec_stats.verify_s += t2 - t1
-        if self.tracer.enabled:
+        if self._traced_run:
             # externally-timed spans carrying the SAME perf_counter stamps
             # that feed SpecStats — summed spans reconcile exactly with
             # draft_s/verify_s
@@ -667,52 +686,68 @@ class ServeEngine:
     def tick(self) -> int:
         """One engine tick: deadline sweep, admissions (plus, paged, up to
         ``budget.chunks_per_tick()`` prefill chunks), then one masked
-        decode step for the decode-ready lanes. Returns the number of
-        tokens generated."""
+        decode step for the decode-ready lanes, then the bookkeeping.
+        Returns the number of tokens generated."""
         tr = self.tracer
         tr.set_tick(self.sched.tick)
-        now = self._rel_now()
-        with tr.phase("expire"):
-            for st in self.sched.expire_deadlines(now):
-                self.metrics.record_request(st)
-        if self.paged:
-            with tr.phase("bind"):
-                self._bind_admissions(now)
-            produced = 0
-            # the budget controller's knob: how much of this tick goes to
-            # prefill (TTFT) vs decode (throughput). Same warm chunk
-            # signatures either way — only the count changes.
-            for _ in range(self.budget.chunks_per_tick()):
-                if self.sched.prefill_head() is None:
-                    break
-                produced += self._prefill_tick(now)
-        else:
-            produced = self._admit_all(now)
-        mask = self.sched.decode_mask()
-        ready = int(mask.sum())
-        if ready and self.spec:
-            produced += self._spec_round(mask)
-        elif ready:
-            toks = np.where(mask, self._next_tok, self.pad_id)
-            self.attrib.dispatch("decode")
-            with tr.phase("decode", n=ready):
-                logits, self.state = self.art.decode_fn(
-                    self.params, self.state,
-                    jnp.asarray(toks[:, None], jnp.int32),
-                    jnp.asarray(mask, jnp.int32))
-                np_logits = np.asarray(logits)
+        with tr.phase("tick"):
             now = self._rel_now()
-            with tr.phase("sample", n=ready):
-                for slot in np.flatnonzero(mask):
-                    st = self.sched.slots[slot]
-                    tok = self._sample(np_logits[slot], st)
-                    st.append(tok, now, tick=self.sched.tick)
-                    self._next_tok[slot] = tok
-                    produced += 1
-                    reason = ("length" if len(st.tokens) >= self._budget(st)
-                              else st.should_stop())
-                    if reason:
-                        self._finish(st, reason, now)
+            with tr.phase("expire"):
+                for st in self.sched.expire_deadlines(now):
+                    self.metrics.record_request(st)
+            if self.paged:
+                with tr.phase("bind"):
+                    self._bind_admissions(now)
+                produced = 0
+                # the budget controller's knob: how much of this tick goes
+                # to prefill (TTFT) vs decode (throughput). Same warm chunk
+                # signatures either way — only the count changes.
+                for _ in range(self.budget.chunks_per_tick()):
+                    if self.sched.prefill_head() is None:
+                        break
+                    produced += self._prefill_tick(now)
+            else:
+                produced = self._admit_all(now)
+            mask = self.sched.decode_mask()
+            ready = int(mask.sum())
+            if ready and self.spec:
+                produced += self._spec_round(mask)
+            elif ready:
+                produced += self._decode(mask, ready)
+            with tr.phase("account"):
+                self._account(ready, produced)
+        return produced
+
+    def _decode(self, mask: np.ndarray, ready: int) -> int:
+        """One masked decode step for the ``ready`` lanes of ``mask`` and
+        their sampled tokens. Returns the number of tokens generated."""
+        tr = self.tracer
+        toks = np.where(mask, self._next_tok, self.pad_id)
+        self.attrib.dispatch("decode")
+        with tr.phase("decode", n=ready):
+            logits, self.state = self.art.decode_fn(
+                self.params, self.state,
+                jnp.asarray(toks[:, None], jnp.int32),
+                jnp.asarray(mask, jnp.int32))
+            np_logits = self._fetch(logits)
+        now = self._rel_now()
+        produced = 0
+        with tr.phase("sample", n=ready):
+            for slot in np.flatnonzero(mask):
+                st = self.sched.slots[slot]
+                tok = self._sample(np_logits[slot], st)
+                st.append(tok, now, tick=self.sched.tick)
+                self._next_tok[slot] = tok
+                produced += 1
+                reason = ("length" if len(st.tokens) >= self._budget(st)
+                          else st.should_stop())
+                if reason:
+                    self._finish(st, reason, now)
+        return produced
+
+    def _account(self, ready: int, produced: int) -> None:
+        """The tick's bookkeeping: block-pool and tick metrics, the tick
+        counter, and the registry publish at its cadence."""
         if self.paged:
             self.metrics.record_block_pool(
                 self.sched.pool, self.sched.live_tokens(),
@@ -727,9 +762,8 @@ class ServeEngine:
                 and self.sched.tick % self.metrics_interval_ticks == 0):
             self._publish_registry()
             self.registry.snapshot(tick=self.sched.tick)
-            if self.tracer.enabled:
+            if self._traced_run:
                 self._emit_counters()
-        return produced
 
     def _emit_counters(self) -> None:
         """Perfetto counter tracks at the metrics snapshot cadence
@@ -777,7 +811,7 @@ class ServeEngine:
             self.tick()
 
         listener = None
-        if self.tracer.enabled:
+        if self._traced_run:
             # plan-solve events on the timeline: in steady state none
             # fire; a "plan-lazy_solve" instant IS the regression
             tr = self.tracer
@@ -846,7 +880,7 @@ class ServeEngine:
         self.metrics.slo_burn = self.metrics.slo_burn_summary(
             None if self.ttft_target_ms is None
             else self.ttft_target_ms / 1e3)
-        if self.tracer.enabled:
+        if self._traced_run:
             self.metrics.timing = self.tracer.phase_summary()
             for name, durs in self.tracer.phase_durations().items():
                 h = self.registry.histogram(

@@ -13,8 +13,8 @@ from repro import models
 from repro.core.context import current_context, use_context
 from repro.core.plancache import PlanCache
 from repro.launch.mesh import make_local_mesh
-from repro.obs import (NULL_TRACER, PHASES, Registry, Tracer, prom_name,
-                       validate_chrome_trace)
+from repro.obs import (NULL_TRACER, PHASES, PROCESS_TRACER, Registry, Tracer,
+                       prom_name, validate_chrome_trace)
 from repro.serve import Request, ServeEngine, SimClock, synthetic_trace
 
 
@@ -42,10 +42,11 @@ def test_tracer_phase_spans_and_summary_are_deterministic():
     s = tr.phase_summary()
     # every span is enter->exit = exactly one clock step = 0.5s
     assert s["phases"]["decode"] == {
-        "kind": "device", "count": 4, "total_s": 2.0, "mean_s": 0.5,
-        "p50_s": 0.5, "p99_s": 0.5}
-    assert s["phases"]["sample"]["kind"] == "host"
-    assert s["device_s"] == 2.0 and s["host_s"] == 0.5
+        "count": 4, "total_s": 2.0, "mean_s": 0.5, "p50_s": 0.5,
+        "p99_s": 0.5}
+    assert s["phases"]["sample"]["total_s"] == 0.5
+    # no host/device split: every span is host time
+    assert set(s) == {"phases", "events_recorded", "events_dropped"}
     assert s["events_recorded"] == 5 and s["events_dropped"] == 0
     assert all(e["tick"] == 3 for e in tr.events)
 
@@ -186,6 +187,108 @@ def test_null_tracer_never_reads_a_clock_or_allocates(monkeypatch):
     assert NULL_TRACER.phase_durations() == {}
 
 
+def test_process_tracer_off_records_nothing_and_reads_no_clock(monkeypatch):
+    """The zero-cost contract for the process-wide tracer: with no
+    profiler session its whole hot-path surface runs with every clock
+    source booby-trapped, returns the shared no-op span and records
+    nothing."""
+    import time as _time
+
+    from jax.profiler import TraceAnnotation
+
+    assert not TraceAnnotation.is_enabled()
+    before = PROCESS_TRACER.phase_summary()["events_recorded"]
+
+    def bomb():
+        raise AssertionError("the process-wide tracer read a clock")
+
+    monkeypatch.setattr(_time, "perf_counter", bomb)
+    monkeypatch.setattr(_time, "monotonic", bomb)
+    monkeypatch.setattr(_time, "time", bomb)
+    PROCESS_TRACER.set_tick(9)
+    span = PROCESS_TRACER.phase("tick")
+    assert span is NULL_TRACER.phase("tick")      # the shared no-op span
+    with span:
+        with PROCESS_TRACER.phase("decode", n=4):
+            pass
+    PROCESS_TRACER.phase_span("spec-draft", 1.0, 2.0)
+    PROCESS_TRACER.instant("plan-miss", key="k")
+    PROCESS_TRACER.counter("attrib", {"compute": 1.0})
+    PROCESS_TRACER.request_event("submit", 1)
+    PROCESS_TRACER.request_event("finish", 1, reason="stop")
+    assert PROCESS_TRACER.phase_summary()["events_recorded"] == before
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: a profiler session
+    runs while ``on`` is set, and every entered annotation is logged."""
+
+    on = False
+    log: list = []
+
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, kw
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.on
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.kw))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, self.kw))
+
+
+def test_spans_annotate_the_profiler_only_inside_a_session(monkeypatch):
+    from repro.obs import trace as trace_mod
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", _FakeAnnotation)
+    monkeypatch.setattr(_FakeAnnotation, "log", [])
+    gated = Tracer(clock=FakeClock(), profiled_only=True)
+    always = Tracer(clock=FakeClock())
+    for on, tick in ((False, 0), (True, 1), (False, 2)):
+        _FakeAnnotation.on = on
+        for tr in (gated, always):
+            tr.set_tick(tick)
+            with tr.phase("tick"):
+                with tr.phase("wait"):
+                    pass
+    _FakeAnnotation.on = False
+    # the gated recorder keeps only the profiled tick; the caller's
+    # tracer keeps all three; both annotate only the profiled one
+    assert [e["tick"] for e in gated.events] == [1, 1]
+    assert [e["tick"] for e in always.events] == [0, 0, 1, 1, 2, 2]
+    assert _FakeAnnotation.log == [
+        ("enter", "serve.tick", {"tick": 1}),
+        ("enter", "serve.wait", {"tick": 1}),
+        ("exit", "serve.wait", {"tick": 1}),
+        ("exit", "serve.tick", {"tick": 1}),
+    ] * 2
+
+
+def test_tracer_spans_query_by_name_and_window():
+    tr = Tracer(clock=FakeClock(1.0))          # reset reads 1.0
+    for tick in range(3):
+        tr.set_tick(tick)
+        with tr.phase("tick"):                 # 2 + 6k .. 7 + 6k
+            with tr.phase("wait"):             # 3 + 6k .. 4 + 6k
+                pass
+            tr.phase_span("fetch", 4.5 + 6 * tick, 4.75 + 6 * tick)
+            with tr.phase("sample"):           # 5 + 6k .. 6 + 6k
+                pass
+    ticks = tr.spans("tick", 0.0, 100.0)
+    assert ticks == [("tick", 2.0 + 6 * k, 7.0 + 6 * k, k) for k in range(3)]
+    assert (ticks[1].start, ticks[1].end, ticks[1].tick) == (8.0, 13.0, 1)
+    # only spans lying wholly inside the window
+    assert [s.tick for s in tr.spans("tick", 7.5, 13.0)] == [1]
+    assert [s.tick for s in tr.spans("tick", 7.5, 12.5)] == []
+    assert [(s.start, s.end) for s in tr.spans("fetch", 14.0, 19.0)] \
+        == [(16.5, 16.75)]
+    assert [s.name for s in tr.spans("wait", 0.0, 100.0)] == ["wait"] * 3
+    assert tr.spans("reclaim", 0.0, 100.0) == []
+
+
 def test_tracer_ring_wraparound_still_exports_valid_chrome_json():
     """Once the ring wraps, begin events may be gone while ends survive;
     the export must degrade those to balanced instants, not emit a file
@@ -231,9 +334,12 @@ def test_counter_tracks_export_and_validate():
 
 
 def test_phase_glossary_covers_engine_phases():
-    assert set(PHASES.values()) <= {"host", "device"}
-    for name in ("admit", "bind", "prefill-chunk", "spec-draft",
-                 "spec-verify", "decode", "sample", "expire", "reclaim"):
+    # a plain glossary: every name says what it covers, with no kind
+    assert all(isinstance(v, str) and v not in ("host", "device")
+               for v in PHASES.values())
+    for name in ("tick", "admit", "bind", "prefill-chunk", "spec-draft",
+                 "spec-verify", "decode", "wait", "fetch", "sample",
+                 "account", "expire", "reclaim"):
         assert name in PHASES
 
 
@@ -372,8 +478,11 @@ def test_traced_run_is_bit_identical_to_untraced(dense_setup):
             r.pop("request_id")
         return engine, toks, d
 
+    recorded = PROCESS_TRACER.phase_summary()["events_recorded"]
     off_engine, off_toks, off_d = go(None)
-    assert off_engine.tracer is NULL_TRACER
+    assert off_engine.tracer is PROCESS_TRACER
+    # no profiler session: the process-wide tracer recorded nothing
+    assert PROCESS_TRACER.phase_summary()["events_recorded"] == recorded
     tr = Tracer()
     _, on_toks, on_d = go(tr)
     assert on_toks == off_toks
@@ -383,7 +492,8 @@ def test_traced_run_is_bit_identical_to_untraced(dense_setup):
     on_d.pop("attribution")
     assert on_d == off_d    # bit-identical modulo the traced-only sections
     assert timing["phases"]["decode"]["count"] > 0
-    for name in ("expire", "bind", "prefill-chunk", "sample"):
+    for name in ("tick", "expire", "bind", "prefill-chunk", "wait", "fetch",
+                 "sample", "account"):
         assert name in timing["phases"], name
 
 
@@ -498,4 +608,71 @@ def test_reclaim_phase_spans_under_prefix_cache_pressure(dense_setup):
                                    max_new_tokens=[4, 3], seed=2))
     # a tight pool + retained prefixes forces at least one reclaim sweep
     assert "reclaim" in tr.phase_durations()
-    assert m.timing["phases"]["reclaim"]["kind"] == "host"
+    assert m.timing["phases"]["reclaim"]["count"] >= 1
+
+
+def test_profiled_run_puts_serve_spans_on_the_profiler_clock(dense_setup,
+                                                             tmp_path):
+    """Inside a profiler session an engine given no tracer writes its
+    spans to the profiler trace's host plane as ``serve.<phase>`` and to
+    the process-wide recorder, each child inside its tick, and serves the
+    same tokens as without a session."""
+    import glob
+    import time
+
+    from jax.profiler import ProfileData
+
+    cfg, mesh, params = dense_setup
+    common = dict(num_slots=2, max_len=24, prompt_pad=8, kv_block_size=4,
+                  num_kv_blocks=17, prefill_chunk=4)
+    spec = [(8, 4), (4, 6), (6, 2)]
+
+    def go():
+        engine = ServeEngine(cfg, mesh, params, **common)
+        engine.plan_warmup()
+        m = engine.run(_reqs(spec))
+        toks = sorted((st.request.prompt.tobytes(), tuple(st.tokens))
+                      for st in engine.finished)
+        return toks, m.ticks
+
+    off_toks, _ = go()
+    t0 = time.perf_counter()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        on_toks, n_ticks = go()
+    finally:
+        jax.profiler.stop_trace()
+    t1 = time.perf_counter()
+    assert on_toks == off_toks
+
+    children = ("expire", "bind", "prefill-chunk", "decode", "wait",
+                "fetch", "sample", "account")
+    # the recorder: every tick of the run, stamped inside the session
+    ticks = {t.tick: t for t in PROCESS_TRACER.spans("tick", t0, t1)}
+    assert sorted(ticks) == list(range(n_ticks))
+    for name in children:
+        spans = PROCESS_TRACER.spans(name, t0, t1)
+        assert spans, name
+        for s in spans:
+            t = ticks[s.tick]
+            assert t.start <= s.start <= s.end <= t.end, (name, s, t)
+    for d in PROCESS_TRACER.spans("decode", t0, t1):
+        # each decode holds its own wait and fetch, one of each
+        for name in ("wait", "fetch"):
+            assert len(PROCESS_TRACER.spans(name, d.start, d.end)) == 1
+
+    # the profiler trace: the same spans on the host plane
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = ProfileData.from_file(path)
+    host = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+             int(dict(ev.stats)["tick"]))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("serve.")]
+    names = {h[0] for h in host}
+    assert {f"serve.{n}" for n in ("tick",) + children} <= names, names
+    xticks = {h[3]: h for h in host if h[0] == "serve.tick"}
+    assert sorted(xticks) == list(range(n_ticks))
+    for name, start, end, tick in host:
+        t = xticks[tick]
+        assert t[1] <= start <= end <= t[2], (name, tick)

@@ -85,10 +85,14 @@ def _metrics(drifted=False, recon=0.0):
                       "tokens_per_tick": 2.0, "admissions": 4,
                       "preemptions": 0, "deadline_missed": 0,
                       "deferred_admissions": 0, "policy": "fifo"},
-        "timing": {"phases": {"decode": {
-            "kind": "device", "count": 10, "total_s": 1.0,
-            "mean_s": 0.1, "p50_s": 0.1, "p99_s": 0.1}},
-            "host_s": 0.0, "device_s": 1.0, "events_dropped": 0},
+        "timing": {"phases": {
+            "tick": {"count": 10, "total_s": 1.2, "mean_s": 0.12,
+                     "p50_s": 0.12, "p99_s": 0.12},
+            "decode": {"count": 10, "total_s": 1.0, "mean_s": 0.1,
+                       "p50_s": 0.1, "p99_s": 0.1},
+            "wait": {"count": 10, "total_s": 0.9, "mean_s": 0.09,
+                     "p50_s": 0.09, "p99_s": 0.09}},
+            "events_recorded": 30, "events_dropped": 0},
         "attribution": {
             "signatures": 1, "attributed_device_s": 1.0 - recon,
             "traced_device_s": 1.0, "reconciliation_error": recon,
@@ -122,6 +126,8 @@ def test_serve_doctor_report_and_findings(tmp_path, capsys):
         assert section in text
     assert "burning its SLO budget at 5.0x" in text
     assert "memory-bound" in text
+    assert "top phase: decode" in text
+    assert "host-bound" not in text     # 0.9 s of 1.2 s waits on the device
 
 
 def test_serve_doctor_gates(tmp_path):

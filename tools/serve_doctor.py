@@ -3,7 +3,7 @@
 
 Ingests the metrics JSON a serve-engine run wrote (``--metrics-json``)
 and, optionally, its flight-recorder Chrome trace (``--trace-out``), and
-prints a ranked diagnosis: where the device time went per phase, which
+prints a ranked diagnosis: where the tick time went per phase, which
 GEMM signatures dominate and whether the balance auditor considers them
 compute-bound, memory-bound or *drifted* (with the suggested re-solve),
 how hard the block pool / prefix trie are being pressed, and which SLO
@@ -37,38 +37,45 @@ def _section(lines: list[str], title: str) -> None:
 
 
 def _phase_report(lines: list[str], timing: dict) -> list[str]:
-    """Ranked per-phase time table; returns diagnosis strings."""
+    """Ranked per-phase time table; returns diagnosis strings.
+
+    Spans nest (every phase inside ``tick``; ``wait`` and ``fetch``
+    inside ``decode``), so shares are of the ticks' total time."""
     findings: list[str] = []
     phases = timing.get("phases", {})
     if not phases:
         lines.append("(untraced run: no timing section — rerun with "
                      "--trace-out for phase and attribution analysis)")
         return findings
-    total = sum(p["total_s"] for p in phases.values()) or 1.0
+    tick_s = phases.get("tick", {}).get("total_s")
+    total = tick_s or sum(p["total_s"] for p in phases.values()) or 1.0
     ranked = sorted(phases.items(), key=lambda kv: -kv[1]["total_s"])
-    lines.append(f"{'phase':<16} {'kind':<7} {'count':>7} {'total_s':>9} "
+    lines.append(f"{'phase':<16} {'count':>7} {'total_s':>9} "
                  f"{'share':>6} {'mean_s':>10} {'p99_s':>10}")
     for name, p in ranked:
         lines.append(
-            f"{name:<16} {p['kind']:<7} {p['count']:>7} "
+            f"{name:<16} {p['count']:>7} "
             f"{p['total_s']:>9.3f} {p['total_s']/total:>6.2f} "
             f"{p['mean_s']:>10.5f} {p['p99_s']:>10.5f}")
-    lines.append(f"host {timing.get('host_s', 0.0):.3f}s / device "
-                 f"{timing.get('device_s', 0.0):.3f}s; "
-                 f"{timing.get('events_dropped', 0)} events dropped")
-    top_name, top = ranked[0]
+    lines.append(f"{timing.get('events_dropped', 0)} events dropped")
+    top_name, top = next(((n, p) for n, p in ranked if n != "tick"),
+                         ranked[0])
     findings.append(
-        f"top phase: {top_name} ({top['kind']}) with "
-        f"{top['total_s']:.3f}s ({top['total_s']/total:.0%} of phase time)")
+        f"top phase: {top_name} with {top['total_s']:.3f}s "
+        f"({top['total_s']/total:.0%} of tick time)")
     if timing.get("events_dropped", 0):
         findings.append(
             f"tracer dropped {timing['events_dropped']} events — raise "
             f"--trace-ring-events for a complete timeline")
-    host_s, device_s = timing.get("host_s", 0.0), timing.get("device_s", 0.0)
-    if host_s > device_s > 0:
-        findings.append(
-            f"host-bound: {host_s:.3f}s host vs {device_s:.3f}s device — "
-            f"sampling/bookkeeping dominates the modeled GEMM work")
+    if tick_s:
+        # the host's part of a tick: everything but blocking on the device
+        wait_s = phases.get("wait", {}).get("total_s", 0.0)
+        host_s = tick_s - wait_s
+        if host_s > wait_s:
+            findings.append(
+                f"host-bound: {host_s:.3f}s of {tick_s:.3f}s tick time "
+                f"not waiting on the device — dispatch, sampling and "
+                f"bookkeeping outweigh the wait for logits")
     return findings
 
 
