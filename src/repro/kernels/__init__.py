@@ -2,6 +2,7 @@
 
 matmul.py        — output-stationary tiled GEMM (the paper's core design)
 decode_matvec.py — decode-time skinny GEMM/GEMV (paper §5.3.4 future work)
+paged_attention.py — decode attention over the live blocks of a KV pool
 ops.py           — jit'd public wrappers (padding, plan selection, fallback)
 ref.py           — pure-jnp oracles
 """

@@ -16,6 +16,9 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.context import current_context
+from repro.kernels import paged_attention as pa
+from repro.kernels.ops import resolve_backend
 from repro.layers import common as cm
 from repro.quant import int8 as q8
 from repro.quant.kvcache import KVCacheDtype
@@ -501,15 +504,32 @@ def paged_prefill_attention(
     return cm.dense(o.reshape(B, C, -1), p.wo), new_cache
 
 
+def decode_attention_backend(quantized: bool) -> str | None:
+    """The kernel backend paged decode attention runs on, or None where it
+    takes the gather path.
+
+    The context's matmul backend chooses, as it does for the GEMMs
+    (:func:`repro.kernels.ops.resolve_backend`): 'pallas' (or 'auto' on a
+    TPU) runs the Pallas kernel, 'interpret' its body in the interpreter,
+    'xla' (or 'auto' elsewhere) the gather. An int8 pool always gathers:
+    its dequantize lives in the gather.
+    """
+    backend = resolve_backend(current_context().matmul_backend)
+    return None if quantized or backend == "xla" else backend
+
+
 def paged_decode_attention(
     p: AttnParams, x: jax.Array, cache: PagedKVCache, *,
     rope_theta: float = 10000.0, use_rope: bool = True,
     active: jax.Array | None = None,
+    pools: tuple[jax.Array, jax.Array] | None = None,
+    layer: jax.Array | None = None,
 ) -> tuple[jax.Array, PagedKVCache]:
-    """One decode step against the block pool; numerics-identical to the
-    contiguous per-slot :func:`decode_attention` (same masked contraction
-    over the same logical positions — the gather only changes *where* the
-    bytes live).
+    """One decode step against the block pool. On the gather path
+    numerics-identical to the contiguous per-slot :func:`decode_attention`
+    (same masked contraction over the same logical positions — the gather
+    only changes *where* the bytes live); the kernel path sums the same
+    terms in another order.
 
     ``active`` (num_slots,) marks live decode lanes. An inactive lane's
     write is redirected to the null block — unlike the contiguous layout,
@@ -518,6 +538,14 @@ def paged_decode_attention(
     reach table-resolved storage. The returned length advances every slot
     by 1; as with the contiguous path, ``lm.decode_step`` owns the actual
     advance (masked by ``active``).
+
+    ``pools`` — the stacked (L, ...) K and V pools as the step received
+    them — and ``layer`` select the Pallas kernel
+    (:mod:`repro.kernels.paged_attention`; see
+    :func:`decode_attention_backend`): it reads ``pools[.][layer]`` before
+    this step's write, only each active lane's written blocks, and takes
+    the current position from this step's k, v. Without them the step
+    gathers every lane's whole table region from the written pool.
     """
     B, S1, _ = x.shape
     assert S1 == 1
@@ -556,6 +584,20 @@ def paged_decode_attention(
                                     ar < off, ar == off, newv_v)
     new_cache = PagedKVCache(k=ck, v=cv, table=cache.table, length=pos + 1,
                              k_scale=ks, v_scale=vs)
+    n_heads = q.shape[2]
+    scale = hd ** -0.5
+    if pools is not None:
+        backend = decode_attention_backend(quantized=ks is not None)
+        if backend is None:
+            raise ValueError("paged decode attention has no kernel for "
+                             "this pool and backend; pass no pools")
+        o = pa.paged_decode_attention(
+            (q[:, 0] * jnp.asarray(scale, q.dtype)).astype(pools[0].dtype),
+            k[:, 0], v[:, 0], pools[0], pools[1], layer, cache.table, pos,
+            active, interpret=(backend == "interpret"),
+            vmem_limit_bytes=current_context().hw.vmem_limit_bytes)
+        out = cm.dense(o.reshape(B, 1, -1).astype(x.dtype), p.wo)
+        return out, new_cache
     if ks is None:
         gk = ck[cache.table].reshape(B, mb * bs, n_kv, hd)
         gv = cv[cache.table].reshape(B, mb * bs, n_kv, hd)
@@ -564,8 +606,6 @@ def paged_decode_attention(
                                  q.dtype).reshape(B, mb * bs, n_kv, hd)
         gv = q8.dequantize_block(cv[cache.table], vs[cache.table],
                                  q.dtype).reshape(B, mb * bs, n_kv, hd)
-    n_heads = q.shape[2]
-    scale = hd ** -0.5
     kr = _repeat_kv(gk, n_heads // n_kv)
     vr = _repeat_kv(gv, n_heads // n_kv)
     s = jnp.einsum(

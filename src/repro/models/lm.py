@@ -726,13 +726,19 @@ def decode_step(params, tokens, cfg: ModelConfig, state, mesh=None,
         kv = state["kv"]
         paged = isinstance(kv, attn.PagedKVCache)
         quantized = paged and kv.k_scale is not None
+        # the decode attention kernel reads the stacked pools the step was
+        # given, at the scan's layer index, not a per-layer slice
+        kernel = paged and attn.decode_attention_backend(quantized) is not None
 
         def body(carry, inp):
             x = carry
-            if quantized:
+            li = cks = cvs = None
+            if kernel:
+                lp, ck, cv, li = inp
+            elif quantized:
                 lp, ck, cv, cks, cvs = inp
             else:
-                (lp, ck, cv), cks, cvs = inp, None, None
+                lp, ck, cv = inp
             h = apply_norm(cfg, lp["ln1"], x)
             if paged:
                 cache = attn.PagedKVCache(k=ck, v=cv, table=kv.table,
@@ -740,7 +746,8 @@ def decode_step(params, tokens, cfg: ModelConfig, state, mesh=None,
                                           k_scale=cks, v_scale=cvs)
                 y, nc = attn.paged_decode_attention(
                     lp["attn"], h, cache, rope_theta=cfg.rope_theta,
-                    active=active)
+                    active=active,
+                    pools=(kv.k, kv.v) if kernel else None, layer=li)
             else:
                 cache = attn.KVCache(k=ck, v=cv, length=kv.length)
                 y, nc = attn.decode_attention(
@@ -770,6 +777,11 @@ def decode_step(params, tokens, cfg: ModelConfig, state, mesh=None,
             x, (nk, nv, nks, nvs) = jax.lax.scan(
                 body, x,
                 (params["layers"], kv.k, kv.v, kv.k_scale, kv.v_scale))
+        elif kernel:
+            x, (nk, nv) = jax.lax.scan(
+                body, x, (params["layers"], kv.k, kv.v,
+                          jnp.arange(L, dtype=jnp.int32)))
+            nks = nvs = None
         else:
             x, (nk, nv) = jax.lax.scan(
                 body, x, (params["layers"], kv.k, kv.v))

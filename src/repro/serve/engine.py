@@ -59,6 +59,7 @@ import numpy as np
 from repro import models
 from repro.configs.base import ModelConfig
 from repro.core.context import current_context
+from repro.layers import attention as attn
 from repro.obs.attrib import AttributionLedger
 from repro.obs.registry import Registry, prom_name
 from repro.obs.trace import PROCESS_TRACER
@@ -302,6 +303,13 @@ class ServeEngine:
             else self.ttft_target_ms / 1e3,
             max_chunks=self.max_prefill_chunks)
         self._next_tok = np.full((self.num_slots,), self.pad_id, np.int64)
+        # the device's per-lane KV lengths (positions written), set where
+        # the engine's step programs set them
+        self._kv_len = [0] * self.num_slots
+        # which path the paged decode program's attention takes under this
+        # context (layers/attention.decode_attention_backend)
+        self._attn_kernel = self.paged and attn.decode_attention_backend(
+            self.kv_dtype.quantized) is not None
         engine_info = {
             "arch": self.cfg.name,
             "num_slots": self.num_slots,
@@ -579,6 +587,7 @@ class ServeEngine:
                 jnp.asarray(start, jnp.int32),
                 jnp.asarray(n, jnp.int32),
                 jnp.asarray(blocks))
+        self._kv_len[st.slot] = start + n
         self.sched.prefill_advance(st.slot, n)
         self.tracer.request_event("chunk", st.request.request_id,
                                   slot=st.slot, n=n, done=st.prefill_done)
@@ -678,6 +687,7 @@ class ServeEngine:
             kv = self.state["kv"]
             self.state["kv"] = kv._replace(
                 length=jnp.asarray(tgt_len, jnp.int32))
+            self._kv_len = tgt_len.tolist()
             dkv = self.draft_state["kv"]
             self.draft_state["kv"] = dkv._replace(
                 length=jnp.asarray(drf_len, jnp.int32))
@@ -731,6 +741,8 @@ class ServeEngine:
                 jnp.asarray(mask, jnp.int32))
             np_logits = self._fetch(logits)
         now = self._rel_now()
+        if self.paged:
+            self._count_decode_attn(np.flatnonzero(mask).tolist())
         produced = 0
         with tr.phase("sample", n=ready):
             for slot in np.flatnonzero(mask):
@@ -744,6 +756,23 @@ class ServeEngine:
                 if reason:
                     self._finish(st, reason, now)
         return produced
+
+    def _count_decode_attn(self, lanes: list[int]) -> None:
+        """Count the decode step that ran ``lanes`` by its attention path,
+        and on the kernel path the blocks it read: ``ceil(length /
+        block_size)`` a lane, the length being the positions written
+        before the step; then advance the lanes' lengths."""
+        m, lens, bs = self.metrics, self._kv_len, self.kv_block_size
+        read = 0
+        for s in lanes:
+            read += (lens[s] + bs - 1) // bs
+            lens[s] += 1
+        if self._attn_kernel:
+            m.decode_attn_kernel_steps += 1
+            m.decode_attn_blocks_read += read
+            m.decode_attn_blocks_table += len(lanes) * self.art.max_blocks
+        else:
+            m.decode_attn_fallback_steps += 1
 
     def _account(self, ready: int, produced: int) -> None:
         """The tick's bookkeeping: block-pool and tick metrics, the tick
@@ -933,6 +962,10 @@ class ServeEngine:
             "generated_tokens": m.generated_tokens,
             "occupancy_sum": m.occupancy_sum,
             "queue_peak": m.queue_peak,
+            "decode_attn_kernel_steps": m.decode_attn_kernel_steps,
+            "decode_attn_fallback_steps": m.decode_attn_fallback_steps,
+            "decode_attn_blocks_read": m.decode_attn_blocks_read,
+            "decode_attn_blocks_table": m.decode_attn_blocks_table,
         })
         reg.ingest("serve_sched", self.sched.counters())
         reg.ingest("serve_budget", self.budget.stats())

@@ -41,6 +41,12 @@ class EngineMetrics:
     generated_tokens: int = 0
     occupancy_sum: int = 0        # sum over ticks of occupied slots
     queue_peak: int = 0
+    # paged decode attention (docs/observability.md): steps by path, and
+    # on kernel steps the pool blocks read against the table's extent
+    decode_attn_kernel_steps: int = 0
+    decode_attn_fallback_steps: int = 0
+    decode_attn_blocks_read: int = 0
+    decode_attn_blocks_table: int = 0
     admissions: int = 0
     deferred_admissions: int = 0
     evictions: dict[str, Any] = dataclasses.field(default_factory=dict)
@@ -287,6 +293,10 @@ class EngineMetrics:
                 "deadline_missed": self.deadline_missed,
                 "policy": self.policy,
                 "queue_peak": self.queue_peak,
+                "decode_attn_kernel_steps": self.decode_attn_kernel_steps,
+                "decode_attn_fallback_steps": self.decode_attn_fallback_steps,
+                "decode_attn_blocks_read": self.decode_attn_blocks_read,
+                "decode_attn_blocks_table": self.decode_attn_blocks_table,
             },
             "requests": list(self.requests),
             "slo": self.slo_summary(),

@@ -77,6 +77,10 @@ GOLDEN = {
     "aggregate.deadline_missed": INT,
     "aggregate.policy": STR,
     "aggregate.queue_peak": INT,
+    "aggregate.decode_attn_kernel_steps": INT,
+    "aggregate.decode_attn_fallback_steps": INT,
+    "aggregate.decode_attn_blocks_read": INT,
+    "aggregate.decode_attn_blocks_table": INT,
     # -------------------------------------------------------- requests
     "requests.*.request_id": INT,
     "requests.*.priority": INT,

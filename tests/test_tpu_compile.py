@@ -8,6 +8,8 @@ a block not aligned to the tiling. The topology is described inside a
 fixture, never at import, so only the worker that runs this file loads the
 TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -100,3 +102,37 @@ def test_main_path_kernel_compiles_for_v5e(
     assert "tpu_custom_call" in compiled.as_text()
     assert mm.vmem_bytes(plan.bm, plan.bk, plan.bn, din.itemsize,
                          dout.itemsize) <= V5E.vmem_bytes
+
+
+@pytest.mark.parametrize(
+    "H,Hkv,view_is_bitcast", [(20, 20, True), (48, 8, False)],
+    ids=["qwen1.5-4b_mha", "internlm2-20b_gqa"])
+def test_paged_decode_attention_compiles_for_v5e(one_chip, H, Hkv,
+                                                 view_is_bitcast):
+    """The decode attention kernel over a 40-layer, 600-block bf16 pool of
+    16-token blocks, 16 lanes of 56 blocks: Mosaic takes the strided block
+    DMAs and the per-head contractions. With 20 KV heads the TPU keeps the
+    block offset second-minor, and the kernel's head-major view of the pool
+    is a bitcast; with 8 it keeps the heads second-minor, and the view is a
+    transposed copy (PERF.md, open questions)."""
+    from repro.kernels.paged_attention import paged_decode_attention
+
+    B, Dh, L, nb, bs, mb = 16, 128, 40, 600, 16, 56
+    bf = jnp.bfloat16
+
+    def arg(shape, dtype=bf):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = jax.jit(lambda *a: paged_decode_attention(
+        *a, vmem_limit_bytes=V5E.vmem_limit_bytes))
+    pool = arg((L, nb, bs, Hkv, Dh))
+    compiled = fn.lower(
+        arg((B, H, Dh)), arg((B, Hkv, Dh)), arg((B, Hkv, Dh)), pool, pool,
+        arg((), jnp.int32), arg((B, mb), jnp.int32), arg((B,), jnp.int32),
+        arg((B,), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    pool_ops = re.findall(
+        rf"= bf16\[{L},{nb},{Hkv},{bs},{Dh}\]\S* (\w+)\(", hlo)
+    assert pool_ops
+    assert (set(pool_ops) == {"bitcast"}) == view_is_bitcast
