@@ -181,8 +181,9 @@ def paged_decode_attention(
 
     q (B, H, Dh), scaled and in the pool's dtype; k_new, v_new (B, Hkv, Dh)
     the current position's key and value; k_pool, v_pool
-    (L, blocks, block_size, Hkv, Dh) the stacked pools before this step's
-    write; ``layer`` the layer to read; ``table`` (B, max_blocks);
+    (L, blocks, block_size, Hkv, Dh) the stacked pools, with or without
+    the current position written (positions ``>= length`` are masked);
+    ``layer`` the layer to read; ``table`` (B, max_blocks);
     ``length`` (B,) positions already in the pool per lane; ``active`` (B,)
     lanes to attend for (others read no block). Returns (B, H, Dh) in q's
     dtype; an inactive lane's row is its current value, finite.

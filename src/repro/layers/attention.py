@@ -21,7 +21,6 @@ from repro.kernels import paged_attention as pa
 from repro.kernels.ops import resolve_backend
 from repro.layers import common as cm
 from repro.quant import int8 as q8
-from repro.quant.kvcache import KVCacheDtype
 
 NEG_INF = -1e30
 
@@ -368,6 +367,9 @@ class PagedKVCache(NamedTuple):
     pre-quantization layout.
     """
 
+    # k, v and the scales lead with the layer axis (L, ...) in the decode
+    # step and prefill chunk, which carry every layer's pool; the verify
+    # step hands its attention one layer's slice
     k: jax.Array        # (num_blocks, block_size, Hkv, Dh) bf16 | int8
     v: jax.Array        # (num_blocks, block_size, Hkv, Dh) bf16 | int8
     table: jax.Array    # (num_slots, max_blocks) int32 pool-block ids
@@ -376,70 +378,94 @@ class PagedKVCache(NamedTuple):
     v_scale: jax.Array | None = None   # (num_blocks, Hkv) f32, int8 only
 
 
-def init_paged_kv_cache(num_slots, num_blocks, block_size, max_blocks,
-                        n_kv_heads, head_dim, dtype=jnp.bfloat16,
-                        kv_dtype=None):
-    kv_dtype = KVCacheDtype.parse(kv_dtype)
-    if kv_dtype.quantized:
-        sd = kv_dtype.storage_dtype
-        # scales start at 1.0, never 0: a zero block dequantizes to 0
-        # either way and every scale a gather can read stays finite
-        return PagedKVCache(
-            k=jnp.zeros((num_blocks, block_size, n_kv_heads, head_dim), sd),
-            v=jnp.zeros((num_blocks, block_size, n_kv_heads, head_dim), sd),
-            table=jnp.zeros((num_slots, max_blocks), jnp.int32),
-            length=jnp.zeros((num_slots,), jnp.int32),
-            k_scale=jnp.ones((num_blocks, n_kv_heads), jnp.float32),
-            v_scale=jnp.ones((num_blocks, n_kv_heads), jnp.float32),
-        )
-    return PagedKVCache(
-        k=jnp.zeros((num_blocks, block_size, n_kv_heads, head_dim), dtype),
-        v=jnp.zeros((num_blocks, block_size, n_kv_heads, head_dim), dtype),
-        table=jnp.zeros((num_slots, max_blocks), jnp.int32),
-        length=jnp.zeros((num_slots,), jnp.int32),
-    )
+def _requantize(old_q, old_scale, keep_old, write_new, newv):
+    """Dequant-merge-requantize of whole int8 pool blocks.
+
+    ``old_q`` (..., block_size, Hkv, Dh) and ``old_scale`` (..., Hkv) are
+    the touched blocks as stored; ``keep_old`` / ``write_new`` (...,
+    block_size) mask block offsets; ``newv`` (..., block_size, Hkv, Dh)
+    holds the incoming values at ``write_new`` positions. Offsets neither
+    kept nor written are zeroed, so a block's stored scale depends only on
+    the tokens that are actually valid in it — stale tails (rejected
+    speculation, reused blocks) can never inflate the grid. Returns the
+    blocks and their scales.
+    """
+    old = q8.dequantize_block(old_q, old_scale)
+    merged = jnp.where(write_new[..., None, None], newv.astype(jnp.float32),
+                       jnp.where(keep_old[..., None, None], old, 0.0))
+    return q8.quantize_block(merged)
 
 
 def _quantized_scatter(pool, scales, blk, keep_old, write_new, newv):
-    """Whole-block dequant-merge-requantize write into the int8 pool.
+    """Whole-block write into the int8 pool (:func:`_requantize`).
 
-    ``blk`` (...,) are the touched pool-block ids; ``keep_old`` /
-    ``write_new`` (..., block_size) mask block offsets; ``newv``
-    (..., block_size, Hkv, Dh) holds the incoming values at ``write_new``
-    positions. Offsets neither kept nor written are zeroed, so a block's
-    stored scale depends only on the tokens that are actually valid in it
-    — stale tails (rejected speculation, reused blocks) can never inflate
-    the grid. Distinct lanes own distinct blocks, so duplicate scatter
-    indices only ever collide on the null block 0, whose content is never
-    validly read.
+    ``blk`` (...,) are the touched pool-block ids. Distinct lanes own
+    distinct blocks, so duplicate scatter indices only ever collide on
+    the null block 0, whose content is never validly read.
     """
-    old = q8.dequantize_block(pool[blk], scales[blk])
-    merged = jnp.where(write_new[..., None, None], newv.astype(jnp.float32),
-                       jnp.where(keep_old[..., None, None], old, 0.0))
-    qblk, qs = q8.quantize_block(merged)
+    qblk, qs = _requantize(pool[blk], scales[blk], keep_old, write_new,
+                           newv)
     return (pool.at[blk].set(qblk, mode="drop"),
             scales.at[blk].set(qs, mode="drop"))
 
 
+def _write_in_place(pool, update, index):
+    """``pool`` with each ``update[i]`` written at ``index(i)``.
+
+    One ``dynamic_update_slice`` each, which XLA does in place on the
+    pool the layer scan carries. An XLA scatter into the stacked pool
+    would not do: on the TPU it picks a pool layout of its own, and the
+    compiled step then relayouts the whole pool around it, inside the
+    scan as well (``tests/test_tpu_compile.py``).
+    """
+    update = update.astype(pool.dtype)
+    for i in range(update.shape[0]):
+        pool = jax.lax.dynamic_update_slice(pool, update[i], index(i))
+    return pool
+
+
+def _rewrite_blocks(pool, scales, layer, blk, keep_old, write_new, newv):
+    """Rewrite the blocks ``blk`` (n,) of layer ``layer`` whole, in place.
+
+    ``keep_old`` / ``write_new`` (n, block_size) mask block offsets and
+    ``newv`` (n, block_size, Hkv, Dh) holds the incoming values at
+    ``write_new`` positions. A bf16 pool keeps the stored value at every
+    other offset; an int8 pool requantizes (:func:`_requantize`), so
+    there offsets neither kept nor written are zeroed. Returns the pool
+    and the scales (None for bf16).
+    """
+    old = pool[layer, blk]
+    at = lambda i: (layer, blk[i], 0, 0, 0)
+    if scales is None:
+        new = jnp.where(write_new[..., None, None], newv.astype(pool.dtype),
+                        old)
+        return _write_in_place(pool, new[:, None, None], at), None
+    q, qs = _requantize(old, scales[layer, blk], keep_old, write_new, newv)
+    return (_write_in_place(pool, q[:, None, None], at),
+            _write_in_place(scales, qs[:, None, None],
+                            lambda i: (layer, blk[i], 0)))
+
+
 def paged_prefill_attention(
-    p: AttnParams, x: jax.Array, cache: PagedKVCache, *,
+    p: AttnParams, x: jax.Array, cache: PagedKVCache, *, layer: jax.Array,
     slot: jax.Array, start: jax.Array, true_len: jax.Array,
     rope_theta: float = 10000.0, use_rope: bool = True,
 ) -> tuple[jax.Array, PagedKVCache]:
-    """One prefill *chunk* for the request occupying ``slot``.
+    """One prefill *chunk* of layer ``layer`` for the request occupying
+    ``slot``, against the stacked block pools (as in
+    :func:`paged_decode_attention`, which also says how they are written).
 
     ``x`` is (1, C, d): chunk tokens right-padded to the bucket length C;
     ``start`` is how many prompt tokens earlier chunks already wrote, and
     ``true_len`` (<= C) how many of this chunk's tokens are real. The chunk's
-    K/V scatter into the slot's pool blocks at logical positions
-    ``start..start+true_len-1`` (pad positions redirect to the null block),
-    then queries attend causally to the slot's whole written prefix through
-    the block table — so chunked prefill sees exactly the key set whole-
-    prompt prefill sees, position for position.
+    K/V land in the slot's pool blocks at logical positions
+    ``start..start+true_len-1``, then queries attend causally to the slot's
+    whole written prefix through the block table — so chunked prefill sees
+    exactly the key set whole-prompt prefill sees, position for position.
     """
     B, C, _ = x.shape
     assert B == 1
-    nb, bs, n_kv, hd = cache.k.shape
+    _, _, bs, n_kv, hd = cache.k.shape
     mb = cache.table.shape[1]
     q = cm.dense(x, p.wq, p.bq).reshape(B, C, -1, hd)
     k = cm.dense(x, p.wk, p.bk).reshape(B, C, n_kv, hd)
@@ -449,47 +475,37 @@ def paged_prefill_attention(
         sin, cos = cm.rotary_embedding(pos[None, :], hd, rope_theta)
         q = cm.apply_rotary(q, sin, cos)
         k = cm.apply_rotary(k, sin, cos)
-    # scatter the chunk's valid K/V into the slot's blocks
-    valid = jnp.arange(C) < true_len
+    # rewrite every block the chunk touches whole: a chunk of C tokens
+    # spans at most C // bs + 2 consecutive table slots from start // bs;
+    # out-of-table candidates redirect to the null block. Offsets outside
+    # the chunk's real tokens keep what the pool holds (int8: the earlier
+    # chunks' tokens, and zero past the chunk).
     row = cache.table[slot]                               # (max_blocks,)
-    if cache.k_scale is None:
-        blk = jnp.where(valid, row[jnp.minimum(pos // bs, mb - 1)], 0)
-        off = jnp.where(valid, pos % bs, 0)
-        ck = cache.k.at[blk, off].set(k[0].astype(cache.k.dtype),
-                                      mode="drop")
-        cv = cache.v.at[blk, off].set(v[0].astype(cache.v.dtype),
-                                      mode="drop")
-        ks = vs = None
-    else:
-        # int8 pool: rewrite every block the chunk touches whole. A chunk
-        # of C tokens spans at most C // bs + 2 consecutive table slots
-        # from start // bs; out-of-table candidates redirect to block 0.
-        T = C // bs + 2
-        cand_ti = start // bs + jnp.arange(T)
-        blk = jnp.where(cand_ti < mb, row[jnp.minimum(cand_ti, mb - 1)], 0)
-        bpos = cand_ti[:, None] * bs + jnp.arange(bs)[None, :]   # (T, bs)
-        write_new = (bpos >= start) & (bpos < start + true_len)
-        keep_old = bpos < start              # earlier chunks' tokens
-        src = jnp.clip(bpos - start, 0, C - 1)
-        ck, ks = _quantized_scatter(cache.k, cache.k_scale, blk,
-                                    keep_old, write_new, k[0][src])
-        cv, vs = _quantized_scatter(cache.v, cache.v_scale, blk,
-                                    keep_old, write_new, v[0][src])
-    new_cache = PagedKVCache(k=ck, v=cv, table=cache.table,
-                             length=cache.length, k_scale=ks, v_scale=vs)
+    T = C // bs + 2
+    cand_ti = start // bs + jnp.arange(T)
+    blk = jnp.where(cand_ti < mb, row[jnp.minimum(cand_ti, mb - 1)], 0)
+    bpos = cand_ti[:, None] * bs + jnp.arange(bs)[None, :]   # (T, bs)
+    write_new = (bpos >= start) & (bpos < start + true_len)
+    keep_old = bpos < start
+    src = jnp.clip(bpos - start, 0, C - 1)
+    ck, ks = _rewrite_blocks(cache.k, cache.k_scale, layer, blk, keep_old,
+                             write_new, k[0][src])
+    cv, vs = _rewrite_blocks(cache.v, cache.v_scale, layer, blk, keep_old,
+                             write_new, v[0][src])
+    new_cache = cache._replace(k=ck, v=cv, k_scale=ks, v_scale=vs)
     # gather the slot's full logical region (prefix + this chunk) and run
     # the same masked contraction plain_attention would
     n_heads = q.shape[2]
     if ks is None:
-        kr = ck[row].reshape(1, mb * bs, n_kv, hd)
-        vr = cv[row].reshape(1, mb * bs, n_kv, hd)
+        kr = ck[layer, row].reshape(1, mb * bs, n_kv, hd)
+        vr = cv[layer, row].reshape(1, mb * bs, n_kv, hd)
     else:
         # dequantize inside the gather: the pool is read as int8; the
         # bf16 view exists only as this chunk-sized activation
-        kr = q8.dequantize_block(ck[row], ks[row], q.dtype).reshape(
-            1, mb * bs, n_kv, hd)
-        vr = q8.dequantize_block(cv[row], vs[row], q.dtype).reshape(
-            1, mb * bs, n_kv, hd)
+        kr = q8.dequantize_block(ck[layer, row], ks[layer, row],
+                                 q.dtype).reshape(1, mb * bs, n_kv, hd)
+        vr = q8.dequantize_block(cv[layer, row], vs[layer, row],
+                                 q.dtype).reshape(1, mb * bs, n_kv, hd)
     kr = _repeat_kv(kr, n_heads // n_kv)
     vr = _repeat_kv(vr, n_heads // n_kv)
     scale = hd ** -0.5
@@ -519,37 +535,39 @@ def decode_attention_backend(quantized: bool) -> str | None:
 
 
 def paged_decode_attention(
-    p: AttnParams, x: jax.Array, cache: PagedKVCache, *,
+    p: AttnParams, x: jax.Array, cache: PagedKVCache, *, layer: jax.Array,
     rope_theta: float = 10000.0, use_rope: bool = True,
     active: jax.Array | None = None,
-    pools: tuple[jax.Array, jax.Array] | None = None,
-    layer: jax.Array | None = None,
 ) -> tuple[jax.Array, PagedKVCache]:
-    """One decode step against the block pool. On the gather path
-    numerics-identical to the contiguous per-slot :func:`decode_attention`
-    (same masked contraction over the same logical positions — the gather
-    only changes *where* the bytes live); the kernel path sums the same
-    terms in another order.
+    """One decode step of layer ``layer`` against the stacked block pools.
+
+    ``cache`` holds every layer's pool, ``(L, num_blocks, block_size, Hkv,
+    Dh)`` (with int8, and ``(L, num_blocks, Hkv)`` scales): the pools
+    ``lm.decode_step``'s layer scan carries. Each lane's new K and V row
+    is written into ``pools[layer]`` in place (:func:`_write_in_place`; an
+    int8 pool rewrites the lane's current block whole), and the returned
+    cache holds the written pools with the length it was given: the
+    caller owns the advance (masked by ``active``).
 
     ``active`` (num_slots,) marks live decode lanes. An inactive lane's
     write is redirected to the null block — unlike the contiguous layout,
     a vacant lane's table row may reference blocks the allocator has
     already handed to another request, so its pad-token write must never
-    reach table-resolved storage. The returned length advances every slot
-    by 1; as with the contiguous path, ``lm.decode_step`` owns the actual
-    advance (masked by ``active``).
+    reach table-resolved storage.
 
-    ``pools`` — the stacked (L, ...) K and V pools as the step received
-    them — and ``layer`` select the Pallas kernel
-    (:mod:`repro.kernels.paged_attention`; see
-    :func:`decode_attention_backend`): it reads ``pools[.][layer]`` before
-    this step's write, only each active lane's written blocks, and takes
-    the current position from this step's k, v. Without them the step
-    gathers every lane's whole table region from the written pool.
+    Where :func:`decode_attention_backend` names a kernel backend, the
+    Pallas kernel (:mod:`repro.kernels.paged_attention`) reads
+    ``pools[layer]``, only each active lane's written blocks, and takes
+    the current position from this step's k, v. Otherwise the step
+    gathers every lane's whole table region from ``pools[layer]``:
+    numerics-identical to the contiguous per-slot :func:`decode_attention`
+    (same masked contraction over the same logical positions — the gather
+    only changes *where* the bytes live); the kernel sums the same terms
+    in another order.
     """
     B, S1, _ = x.shape
     assert S1 == 1
-    nb, bs, n_kv, hd = cache.k.shape
+    _, _, bs, n_kv, hd = cache.k.shape
     mb = cache.table.shape[1]
     q = cm.dense(x, p.wq, p.bq).reshape(B, 1, -1, hd)
     k = cm.dense(x, p.wk, p.bk).reshape(B, 1, n_kv, hd)
@@ -560,51 +578,47 @@ def paged_decode_attention(
                                        hd, rope_theta)
         q = cm.apply_rotary(q, sin, cos)
         k = cm.apply_rotary(k, sin, cos)
-    rows = jnp.arange(B)
     ti = jnp.minimum(pos // bs, mb - 1)
-    blk = cache.table[rows, ti]
+    blk = cache.table[jnp.arange(B), ti]
     if active is not None:
         blk = jnp.where(active.astype(bool), blk, 0)       # null-block spill
-    if cache.k_scale is None:
-        ck = cache.k.at[blk, pos % bs].set(k[:, 0].astype(cache.k.dtype),
-                                           mode="drop")
-        cv = cache.v.at[blk, pos % bs].set(v[:, 0].astype(cache.v.dtype),
-                                           mode="drop")
+    off = pos % bs
+    quantized = cache.k_scale is not None
+    if not quantized:
+        row_at = lambda b: (layer, blk[b], off[b], 0, 0)
+        ck = _write_in_place(cache.k, k[:, :, None, None], row_at)
+        cv = _write_in_place(cache.v, v[:, :, None, None], row_at)
         ks = vs = None
     else:
         # int8 pool: each lane rewrites its current block whole — keep
         # the offsets before the append point, zero the tail past it
         ar = jnp.arange(bs)[None, :]
-        off = (pos % bs)[:, None]
-        newv_k = jnp.broadcast_to(k[:, 0][:, None], (B, bs, n_kv, hd))
-        newv_v = jnp.broadcast_to(v[:, 0][:, None], (B, bs, n_kv, hd))
-        ck, ks = _quantized_scatter(cache.k, cache.k_scale, blk,
-                                    ar < off, ar == off, newv_k)
-        cv, vs = _quantized_scatter(cache.v, cache.v_scale, blk,
-                                    ar < off, ar == off, newv_v)
-    new_cache = PagedKVCache(k=ck, v=cv, table=cache.table, length=pos + 1,
-                             k_scale=ks, v_scale=vs)
+        keep, new = ar < off[:, None], ar == off[:, None]
+        ck, ks = _rewrite_blocks(cache.k, cache.k_scale, layer, blk, keep,
+                                 new, k)
+        cv, vs = _rewrite_blocks(cache.v, cache.v_scale, layer, blk, keep,
+                                 new, v)
+    new_cache = cache._replace(k=ck, v=cv, k_scale=ks, v_scale=vs)
     n_heads = q.shape[2]
     scale = hd ** -0.5
-    if pools is not None:
-        backend = decode_attention_backend(quantized=ks is not None)
-        if backend is None:
-            raise ValueError("paged decode attention has no kernel for "
-                             "this pool and backend; pass no pools")
+    backend = decode_attention_backend(quantized)
+    if backend is not None:
         o = pa.paged_decode_attention(
-            (q[:, 0] * jnp.asarray(scale, q.dtype)).astype(pools[0].dtype),
-            k[:, 0], v[:, 0], pools[0], pools[1], layer, cache.table, pos,
-            active, interpret=(backend == "interpret"),
+            (q[:, 0] * jnp.asarray(scale, q.dtype)).astype(ck.dtype),
+            k[:, 0], v[:, 0], ck, cv, layer, cache.table, pos, active,
+            interpret=(backend == "interpret"),
             vmem_limit_bytes=current_context().hw.vmem_limit_bytes)
         out = cm.dense(o.reshape(B, 1, -1).astype(x.dtype), p.wo)
         return out, new_cache
     if ks is None:
-        gk = ck[cache.table].reshape(B, mb * bs, n_kv, hd)
-        gv = cv[cache.table].reshape(B, mb * bs, n_kv, hd)
+        gk = ck[layer, cache.table].reshape(B, mb * bs, n_kv, hd)
+        gv = cv[layer, cache.table].reshape(B, mb * bs, n_kv, hd)
     else:
-        gk = q8.dequantize_block(ck[cache.table], ks[cache.table],
+        gk = q8.dequantize_block(ck[layer, cache.table],
+                                 ks[layer, cache.table],
                                  q.dtype).reshape(B, mb * bs, n_kv, hd)
-        gv = q8.dequantize_block(cv[cache.table], vs[cache.table],
+        gv = q8.dequantize_block(cv[layer, cache.table],
+                                 vs[layer, cache.table],
                                  q.dtype).reshape(B, mb * bs, n_kv, hd)
     kr = _repeat_kv(gk, n_heads // n_kv)
     vr = _repeat_kv(gv, n_heads // n_kv)

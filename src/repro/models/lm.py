@@ -245,6 +245,25 @@ def _shared_block(cfg, sp, x, cache: attn.KVCache | None = None, mode="full"):
     return x, new_cache
 
 
+def _cached_block(cfg, lp, x, attend, mesh, token_mask=None):
+    """A dense or MoE layer around its cached attention ``attend(h) ->
+    (y, cache)``; for MoE only ``token_mask``'s tokens compete for expert
+    capacity. Returns (x, cache)."""
+    y, cache = attend(apply_norm(cfg, lp["ln1"], x))
+    x = x + y
+    h2 = apply_norm(cfg, lp["ln2"], x)
+    if cfg.family == "moe":
+        y2, _ = moe_lib.moe_ffn(
+            lp["moe"], h2, mesh=mesh, top_k=cfg.top_k,
+            capacity_factor=cfg.capacity_factor, activation=cfg.activation,
+            token_mask=token_mask)
+        if cfg.dense_residual:
+            y2 = y2 + mlp_lib.mlp(lp["mlp"], h2, activation=cfg.activation)
+    else:
+        y2 = mlp_lib.mlp(lp["mlp"], h2, activation=cfg.activation)
+    return cm.hint(x + y2, "dp", None, "model"), cache
+
+
 # ---------------------------------------------------------------- forward
 def _maybe_remat(cfg, fn):
     if not cfg.remat:
@@ -578,52 +597,27 @@ def prefill_chunk(params, tokens, cfg: ModelConfig, state, *, slot, start,
     if not isinstance(kv, attn.PagedKVCache):
         raise ValueError("prefill_chunk requires a paged decode state "
                          "(init_decode_state with kv_block_size)")
-    table = kv.table.at[slot].set(blocks)
     x = cm.embed_lookup(params["embed"], tokens, mesh).astype(cfg.dtype)
     C = tokens.shape[1]
-    quantized = kv.k_scale is not None
+    # pad positions must not compete for expert capacity
+    real = (jnp.arange(C) < true_len)[None, :]
 
+    # the stacked pools are the scan's carry, written in place (as in
+    # decode_step)
     def body(carry, inp):
-        x = carry
-        if quantized:
-            lp, ck, cv, cks, cvs = inp
-        else:
-            (lp, ck, cv), cks, cvs = inp, None, None
-        h = apply_norm(cfg, lp["ln1"], x)
-        cache = attn.PagedKVCache(k=ck, v=cv, table=table, length=kv.length,
-                                  k_scale=cks, v_scale=cvs)
-        y, nc = attn.paged_prefill_attention(
-            lp["attn"], h, cache, slot=slot, start=start, true_len=true_len,
-            rope_theta=cfg.rope_theta)
-        x = x + y
-        h2 = apply_norm(cfg, lp["ln2"], x)
-        if cfg.family == "moe":
-            # pad positions must not compete for expert capacity
-            y2, _ = moe_lib.moe_ffn(
-                lp["moe"], h2, mesh=mesh, top_k=cfg.top_k,
-                capacity_factor=cfg.capacity_factor,
-                activation=cfg.activation,
-                token_mask=(jnp.arange(C) < true_len)[None, :])
-            if cfg.dense_residual:
-                y2 = y2 + mlp_lib.mlp(lp["mlp"], h2,
-                                      activation=cfg.activation)
-        else:
-            y2 = mlp_lib.mlp(lp["mlp"], h2, activation=cfg.activation)
-        out = ((nc.k, nc.v, nc.k_scale, nc.v_scale) if quantized
-               else (nc.k, nc.v))
-        return cm.hint(x + y2, "dp", None, "model"), out
+        x, cache = carry
+        lp, li = inp
+        return _cached_block(
+            cfg, lp, x, lambda h: attn.paged_prefill_attention(
+                lp["attn"], h, cache, layer=li, slot=slot, start=start,
+                true_len=true_len, rope_theta=cfg.rope_theta),
+            mesh, real), None
 
-    if quantized:
-        x, (nk, nv, nks, nvs) = jax.lax.scan(
-            body, x, (params["layers"], kv.k, kv.v, kv.k_scale, kv.v_scale))
-    else:
-        x, (nk, nv) = jax.lax.scan(body, x, (params["layers"], kv.k, kv.v))
-        nks = nvs = None
-    new_len = kv.length.at[slot].set(
-        jnp.asarray(start + true_len, jnp.int32))
-    new_state = {**state, "kv": attn.PagedKVCache(
-        k=nk, v=nv, table=table, length=new_len,
-        k_scale=nks, v_scale=nvs)}
+    (x, kv), _ = jax.lax.scan(
+        body, (x, kv._replace(table=kv.table.at[slot].set(blocks))),
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    new_state = {**state, "kv": kv._replace(length=kv.length.at[slot].set(
+        jnp.asarray(start + true_len, jnp.int32)))}
     lp = jnp.broadcast_to(
         jnp.asarray(true_len - 1, jnp.int32), (x.shape[0],))
     h_last = jnp.take_along_axis(x, lp[:, None, None], axis=1)
@@ -663,35 +657,22 @@ def verify_step(params, tokens, cfg: ModelConfig, state, mesh=None,
     quantized = kv.k_scale is not None
 
     def body(carry, inp):
-        x = carry
         if quantized:
             lp, ck, cv, cks, cvs = inp
         else:
             (lp, ck, cv), cks, cvs = inp, None, None
-        h = apply_norm(cfg, lp["ln1"], x)
         cache = attn.PagedKVCache(k=ck, v=cv, table=kv.table,
                                   length=kv.length,
                                   k_scale=cks, v_scale=cvs)
-        y, nc = attn.paged_verify_attention(
-            lp["attn"], h, cache, rope_theta=cfg.rope_theta, active=active)
-        x = x + y
-        h2 = apply_norm(cfg, lp["ln2"], x)
-        if cfg.family == "moe":
-            y2, _ = moe_lib.moe_ffn(
-                lp["moe"], h2, mesh=mesh, top_k=cfg.top_k,
-                capacity_factor=cfg.capacity_factor,
-                activation=cfg.activation,
-                token_mask=(None if active is None
-                            else jnp.broadcast_to((active > 0)[:, None],
-                                                  (B, S))))
-            if cfg.dense_residual:
-                y2 = y2 + mlp_lib.mlp(lp["mlp"], h2,
-                                      activation=cfg.activation)
-        else:
-            y2 = mlp_lib.mlp(lp["mlp"], h2, activation=cfg.activation)
+        x, nc = _cached_block(
+            cfg, lp, carry, lambda h: attn.paged_verify_attention(
+                lp["attn"], h, cache, rope_theta=cfg.rope_theta,
+                active=active),
+            mesh, None if active is None
+            else jnp.broadcast_to((active > 0)[:, None], (B, S)))
         out = ((nc.k, nc.v, nc.k_scale, nc.v_scale) if quantized
                else (nc.k, nc.v))
-        return cm.hint(x + y2, "dp", None, "model"), out
+        return x, out
 
     if quantized:
         x, (nk, nv, nks, nvs) = jax.lax.scan(
@@ -724,74 +705,42 @@ def decode_step(params, tokens, cfg: ModelConfig, state, mesh=None,
 
     if cfg.family in ("dense", "moe"):
         kv = state["kv"]
-        paged = isinstance(kv, attn.PagedKVCache)
-        quantized = paged and kv.k_scale is not None
-        # the decode attention kernel reads the stacked pools the step was
-        # given, at the scan's layer index, not a per-layer slice
-        kernel = paged and attn.decode_attention_backend(quantized) is not None
 
-        def body(carry, inp):
-            x = carry
-            li = cks = cvs = None
-            if kernel:
-                lp, ck, cv, li = inp
-            elif quantized:
-                lp, ck, cv, cks, cvs = inp
-            else:
-                lp, ck, cv = inp
-            h = apply_norm(cfg, lp["ln1"], x)
-            if paged:
-                cache = attn.PagedKVCache(k=ck, v=cv, table=kv.table,
-                                          length=kv.length,
-                                          k_scale=cks, v_scale=cvs)
-                y, nc = attn.paged_decode_attention(
-                    lp["attn"], h, cache, rope_theta=cfg.rope_theta,
-                    active=active,
-                    pools=(kv.k, kv.v) if kernel else None, layer=li)
-            else:
-                cache = attn.KVCache(k=ck, v=cv, length=kv.length)
-                y, nc = attn.decode_attention(
-                    lp["attn"], h, cache, rope_theta=cfg.rope_theta)
-            x = x + y
-            h2 = apply_norm(cfg, lp["ln2"], x)
-            if cfg.family == "moe":
-                # vacant slot lanes must not compete for expert capacity:
-                # a live request's routing would otherwise depend on
-                # unrelated slot occupancy (engine determinism)
-                y2, _ = moe_lib.moe_ffn(
-                    lp["moe"], h2, mesh=mesh, top_k=cfg.top_k,
-                    capacity_factor=cfg.capacity_factor,
-                    activation=cfg.activation,
-                    token_mask=(None if active is None
-                                else (active > 0)[:, None]))
-                if cfg.dense_residual:
-                    y2 = y2 + mlp_lib.mlp(lp["mlp"], h2,
-                                          activation=cfg.activation)
-            else:
-                y2 = mlp_lib.mlp(lp["mlp"], h2, activation=cfg.activation)
-            out = ((nc.k, nc.v, nc.k_scale, nc.v_scale) if quantized
-                   else (nc.k, nc.v))
-            return cm.hint(x + y2, "dp", None, "model"), out
+        # vacant slot lanes must not compete for expert capacity: a live
+        # request's routing would otherwise depend on unrelated slot
+        # occupancy (engine determinism)
+        real = None if active is None else (active > 0)[:, None]
+        step = 1 if active is None else active.astype(kv.length.dtype)
+        if isinstance(kv, attn.PagedKVCache):
+            # the stacked pools are the scan's carry: each layer writes its
+            # new rows into them in place and attends at its own index, so
+            # no per-layer pool slice or whole-pool copy exists
+            def body(carry, inp):
+                x, cache = carry
+                lp, li = inp
+                return _cached_block(
+                    cfg, lp, x, lambda h: attn.paged_decode_attention(
+                        lp["attn"], h, cache, layer=li,
+                        rope_theta=cfg.rope_theta, active=active),
+                    mesh, real), None
 
-        if quantized:
-            x, (nk, nv, nks, nvs) = jax.lax.scan(
-                body, x,
-                (params["layers"], kv.k, kv.v, kv.k_scale, kv.v_scale))
-        elif kernel:
-            x, (nk, nv) = jax.lax.scan(
-                body, x, (params["layers"], kv.k, kv.v,
-                          jnp.arange(L, dtype=jnp.int32)))
-            nks = nvs = None
+            (x, kv), _ = jax.lax.scan(
+                body, (x, kv),
+                (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+            new_state = {"kv": kv._replace(length=kv.length + step)}
         else:
+            def body(carry, inp):
+                lp, ck, cv = inp
+                x, nc = _cached_block(
+                    cfg, lp, carry, lambda h: attn.decode_attention(
+                        lp["attn"], h,
+                        attn.KVCache(k=ck, v=cv, length=kv.length),
+                        rope_theta=cfg.rope_theta),
+                    mesh, real)
+                return x, (nc.k, nc.v)
+
             x, (nk, nv) = jax.lax.scan(
                 body, x, (params["layers"], kv.k, kv.v))
-            nks = nvs = None
-        step = 1 if active is None else active.astype(kv.length.dtype)
-        if paged:
-            new_state = {"kv": attn.PagedKVCache(
-                k=nk, v=nv, table=kv.table, length=kv.length + step,
-                k_scale=nks, v_scale=nvs)}
-        else:
             new_state = {"kv": attn.KVCache(
                 k=nk, v=nv, length=kv.length + step)}
     elif cfg.family == "rwkv":

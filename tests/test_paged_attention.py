@@ -26,11 +26,12 @@ def _kernel_in(jaxpr) -> bool:
     [(4, 4, jnp.bfloat16, 16), (8, 2, jnp.bfloat16, 16),
      (4, 4, jnp.float32, 8), (8, 2, jnp.float32, 4)],
     ids=["mha-bf16", "gqa-bf16", "mha-f32", "gqa-f32"])
-def test_paged_decode_kernel_matches_gather(n_heads, n_kv, dtype, bs):
-    """One layer, the same pool and table, kernel against gather. Active
-    lanes hold 1, bs-1, bs, bs+1 and max_len-1 positions on shuffled,
-    non-contiguous blocks; one inactive lane's row points at blocks other
-    lanes own, another's is all null block."""
+def test_paged_decode_kernel_matches_gather(n_heads, n_kv, dtype, bs,
+                                            monkeypatch):
+    """One layer of a stacked pool, the same table, kernel against gather.
+    Active lanes hold 1, bs-1, bs, bs+1 and max_len-1 positions on
+    shuffled, non-contiguous blocks; one inactive lane's row points at
+    blocks other lanes own, another's is all null block."""
     rng = np.random.default_rng(11)
     hd, L, mb, nb = 32, 2, 12, 64
     max_len = mb * bs
@@ -51,16 +52,18 @@ def test_paged_decode_kernel_matches_gather(n_heads, n_kv, dtype, bs):
     kp = jax.random.normal(k1, shape, jnp.float32).astype(dtype)
     vp = jax.random.normal(k2, shape, jnp.float32).astype(dtype)
     x = jax.random.normal(k3, (B, 1, d), jnp.float32).astype(dtype)
-    layer = 1
-    cache = attn.PagedKVCache(k=kp[layer], v=vp[layer],
-                              table=jnp.asarray(table),
+    cache = attn.PagedKVCache(k=kp, v=vp, table=jnp.asarray(table),
                               length=jnp.asarray(lengths))
     act = jnp.asarray(active)
+    layer = jnp.int32(1)
     with use_context(matmul_backend="interpret", plan_cache=PlanCache()):
-        want, wc = attn.paged_decode_attention(p, x, cache, active=act)
-        got, gc = attn.paged_decode_attention(
-            p, x, cache, active=act, pools=(kp, vp),
-            layer=jnp.int32(layer))
+        got, gc = attn.paged_decode_attention(p, x, cache, layer=layer,
+                                              active=act)
+        # the same GEMMs, the gather in place of the kernel
+        monkeypatch.setattr(attn, "decode_attention_backend",
+                            lambda quantized: None)
+        want, wc = attn.paged_decode_attention(p, x, cache, layer=layer,
+                                               active=act)
     # the write is the same either way
     np.testing.assert_array_equal(np.asarray(gc.k), np.asarray(wc.k))
     np.testing.assert_array_equal(np.asarray(gc.v), np.asarray(wc.v))

@@ -404,6 +404,157 @@ def test_paged_decode_bit_exact_vs_contiguous_per_slot(dense_setup):
             tok = jnp.argmax(lpd[:1, : cfg.vocab_size], -1).astype(jnp.int32)
 
 
+def _prefilled_pool(cfg, params, kv_dtype, prompts, bs=4, max_len=16,
+                    num_kv_blocks=16):
+    """A paged state with each (slot, prompt) prefilled in chunks of 4
+    onto shuffled blocks; the other lanes vacant."""
+    rng = np.random.default_rng(9)
+    lanes, mb = 3, max_len // bs
+    state = models.init_decode_state(
+        cfg, lanes, max_len, per_slot=True, kv_block_size=bs,
+        num_kv_blocks=num_kv_blocks, kv_dtype=kv_dtype)
+    ids = iter(rng.permutation(np.arange(1, num_kv_blocks)))
+    tables = {}
+    for slot, prompt in prompts.items():
+        blocks = np.array([next(ids) for _ in range(mb)], np.int32)
+        tables[slot] = blocks
+        for start in range(0, len(prompt), 4):
+            n = min(4, len(prompt) - start)
+            chunk = np.zeros((1, 4), np.int32)
+            chunk[0, :n] = prompt[start: start + n]
+            _, state = models.prefill_chunk(
+                params, jnp.asarray(chunk), cfg, state,
+                slot=jnp.asarray(slot, jnp.int32),
+                start=jnp.asarray(start, jnp.int32),
+                true_len=jnp.asarray(n, jnp.int32),
+                blocks=jnp.asarray(blocks))
+    return state, tables
+
+
+def _pool_changes(before, after):
+    """(layer, block, offset) rows of the K and V pools that differ, and
+    (layer, block) of the scales, null block left out."""
+    rows = set()
+    for name in ("k", "v", "k_scale", "v_scale"):
+        a, b = getattr(before, name), getattr(after, name)
+        if a is None:
+            continue
+        diff = np.asarray(a) != np.asarray(b)
+        diff = diff.reshape(diff.shape[:3] + (-1,)).any(-1)
+        rows |= {tuple(int(i) for i in r) for r in np.argwhere(diff)
+                 if r[1] != 0}
+    return rows
+
+
+@pytest.mark.parametrize(
+    "backend,kv_dtype",
+    [("xla", None), ("interpret", None), ("xla", "int8"),
+     ("interpret", "int8")],
+    ids=["gather", "kernel", "int8-xla", "int8-interpret"])
+def test_decode_step_writes_only_the_active_lanes_rows(dense_setup, backend,
+                                                       kv_dtype):
+    """One decode step over the pool the layer scan carries (in the model's
+    dtype, or int8, which gathers on either backend) changes, in every
+    layer, only the row each active lane writes (int8: the lane's current
+    block, rewritten whole, and its scales); the inactive lane, whose
+    table row names another lane's blocks, writes the null block. Its
+    logits are those of the contiguous per-slot step over the same keys:
+    bit-exact on the gather, to the kernel's summation order on the
+    kernel, and for int8 within the pinned int8 tolerance (the written
+    block is requantized)."""
+    from repro.layers.attention import KVCache
+    from repro.quant import int8 as q8
+
+    cfg, mesh, params = dense_setup
+    rng = np.random.default_rng(4)
+    bs, L = 4, cfg.n_layers
+    prompts = {0: rng.integers(0, cfg.vocab_size, size=7),
+               1: rng.integers(0, cfg.vocab_size, size=4)}
+    with use_context(matmul_backend=backend, plan_cache=PlanCache()):
+        state, tables = _prefilled_pool(cfg, params, kv_dtype, prompts, bs)
+        # the vacant lane's row still names blocks lane 0 now owns
+        kv = state["kv"]._replace(
+            table=state["kv"].table.at[2].set(tables[0]))
+        state = {"kv": kv}
+        active = jnp.asarray([1, 1, 0], jnp.int32)
+        feed = jnp.asarray([[5], [9], [0]], jnp.int32)
+        got, new = models.decode_step(params, feed, cfg, state,
+                                      active=active)
+        # the contiguous per-slot cache holding each lane's table region
+        table = np.asarray(kv.table)
+
+        def region(pool, scale):
+            g = pool[:, table]
+            if scale is not None:
+                g = q8.dequantize_block(g, scale[:, table], cfg.dtype)
+            return g.reshape(L, 3, -1, *pool.shape[3:]).astype(cfg.dtype)
+
+        cont = {"kv": KVCache(k=region(kv.k, kv.k_scale),
+                              v=region(kv.v, kv.v_scale), length=kv.length)}
+        want, _ = models.decode_step(params, feed, cfg, cont, active=active)
+    want = np.asarray(want[:2, : cfg.vocab_size])
+    got = np.asarray(got[:2, : cfg.vocab_size])
+    if kv_dtype is not None:
+        assert np.abs(got - want).max() <= 0.1
+    elif backend == "xla":
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, rtol=0,
+                                   atol=1e-4 * np.abs(want).max())
+    nkv = new["kv"]
+    np.testing.assert_array_equal(np.asarray(nkv.length), [8, 5, 0])
+    written = {(slot, len(p)) for slot, p in prompts.items()}
+    blocks = {(int(tables[s][pos // bs]), pos % bs) for s, pos in written}
+    if kv_dtype is None:
+        want_rows = {(l, b, o) for l in range(L) for b, o in blocks}
+        assert _pool_changes(kv, nkv) == want_rows
+    else:
+        changed = {(l, b) for l, b, *_ in _pool_changes(kv, nkv)}
+        assert changed == {(l, b) for l in range(L) for b, _ in blocks}
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["plain", "int8"])
+def test_prefill_chunk_writes_only_its_rows(dense_setup, kv_dtype):
+    """A chunk that starts inside a block, as after a prefix-cache hit,
+    changes in every layer only the rows of its real tokens (int8: the
+    blocks that hold them, and their scales), and its logits are
+    whole-prompt prefill's: to 64 ulps of the largest (the chunked-prefill
+    parity test's bound), within the pinned int8 tolerance for int8."""
+    cfg, mesh, params = dense_setup
+    rng = np.random.default_rng(6)
+    bs, L, start, n = 4, cfg.n_layers, 6, 5
+    prompt = rng.integers(0, cfg.vocab_size, size=start + n).astype(np.int32)
+    with use_context():
+        state, tables = _prefilled_pool(cfg, params, kv_dtype,
+                                        {1: prompt[:start]}, bs)
+        kv = state["kv"]
+        chunk = np.zeros((1, 8), np.int32)
+        chunk[0, :n] = prompt[start:]
+        got, new = models.prefill_chunk(
+            params, jnp.asarray(chunk), cfg, state,
+            slot=jnp.asarray(1, jnp.int32),
+            start=jnp.asarray(start, jnp.int32),
+            true_len=jnp.asarray(n, jnp.int32),
+            blocks=jnp.asarray(tables[1]))
+        ref, _ = models.prefill(
+            params, {"tokens": jnp.asarray(prompt[None])}, cfg,
+            models.init_decode_state(cfg, 1, 16))
+    ref = np.asarray(ref[0, : cfg.vocab_size])
+    got = np.asarray(got[0, : cfg.vocab_size])
+    atol = (0.1 if kv_dtype else
+            64 * np.finfo(np.float32).eps * np.abs(ref).max())
+    np.testing.assert_allclose(got, ref, rtol=0, atol=atol)
+    assert int(new["kv"].length[1]) == start + n
+    rows = {(int(tables[1][p // bs]), p % bs)
+            for p in range(start, start + n)}
+    if kv_dtype is None:
+        assert _pool_changes(kv, new["kv"]) == {
+            (l, b, o) for l in range(L) for b, o in rows}
+    else:
+        changed = {(l, b) for l, b, *_ in _pool_changes(kv, new["kv"])}
+        assert changed == {(l, b) for l in range(L) for b, _ in rows}
+
+
 # ------------------------------------------------------- engine parity
 def test_paged_engine_matches_contiguous_engine(dense_setup):
     """The acceptance gate: the same mixed-length trace through the paged

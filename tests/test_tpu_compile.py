@@ -136,3 +136,75 @@ def test_paged_decode_attention_compiles_for_v5e(one_chip, H, Hkv,
         rf"= bf16\[{L},{nb},{Hkv},{bs},{Dh}\]\S* (\w+)\(", hlo)
     assert pool_ops
     assert (set(pool_ops) == {"bitcast"}) == view_is_bitcast
+
+
+@pytest.mark.parametrize("chunk", [None, 512], ids=["decode", "prefill_512"])
+def test_paged_step_writes_the_pool_in_place_for_v5e(one_chip, monkeypatch,
+                                                      chunk):
+    """The paged decode step, and a prefill chunk of 512, at qwen1.5-4b
+    widths on 4 layers, a pool of 600 blocks of 16 and 16 lanes, compiled
+    as the engine compiles them (state donated, Pallas backend): the
+    layer scan carries the stacked pools and writes them in place. No op
+    makes a pool-shaped or per-layer-slice-shaped array but the
+    parameters, their tuple elements, bitcasts (the kernel's head-major
+    view) and dynamic-update-slices (alone or fused), and the program's
+    temporaries do not grow with the pool: the same program over 300
+    blocks has as many. A whole-pool copy or relayout would add a pool
+    (197 MB at 600 blocks) beside the largest temporary that remains, the
+    unembedding's padded weight (780 MB)."""
+    import dataclasses
+
+    from repro import configs as C
+    from repro import models
+    from repro.core.context import GemmContext, use_context
+
+    # the kernels' backend checks the platform; the compile is for a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    L, bs, B, max_len = 4, 16, 16, 896
+    cfg = dataclasses.replace(C.get_config("qwen1.5-4b"), n_layers=L,
+                              param_dtype="bfloat16")
+    Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
+
+    def shapes(fn):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(fn))
+
+    def i32(shape=()):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if chunk is None:
+        fn = jax.jit(lambda p, s, t, a: models.decode_step(
+            p, t, cfg, s, active=a), donate_argnums=(1,))
+        args = (i32((B, 1)), i32((B,)))
+    else:
+        fn = jax.jit(lambda p, s, c, sl, st, n, b: models.prefill_chunk(
+            p, c, cfg, s, slot=sl, start=st, true_len=n, blocks=b),
+            donate_argnums=(1,))
+        args = (i32((1, chunk)), i32(), i32(), i32(), i32((max_len // bs,)))
+
+    def compile_for(nb):
+        ctx = GemmContext(hw=V5E, matmul_backend="pallas",
+                          plan_cache=PlanCache())
+        with use_context(ctx):
+            params = shapes(lambda: models.init(jax.random.PRNGKey(0), cfg))
+            state = shapes(lambda: models.init_decode_state(
+                cfg, B, max_len, per_slot=True, kv_block_size=bs,
+                num_kv_blocks=nb))
+            return fn.lower(params, state, *args).compile()
+
+    nb = 600
+    compiled = compile_for(nb)
+    hlo = compiled.as_text()
+    dims = [f"{L},{nb},{bs},{Hkv},{Dh}", f"{L},{nb},{Hkv},{bs},{Dh}",
+            f"1,{nb},{bs},{Hkv},{Dh}", f"{nb},{bs},{Hkv},{Dh}"]
+    ops = re.findall(rf"= bf16\[(?:{'|'.join(dims)})\]\S* ([\w-]+)\(", hlo)
+    assert "dynamic-update-slice" in ops
+    assert set(ops) <= {"parameter", "get-tuple-element", "bitcast",
+                        "dynamic-update-slice", "fusion"}, sorted(set(ops))
+    # a copy of the pool, or of one layer's slice, would differ by at
+    # least one 300-block layer (24.6 MB) between the two
+    temps = [c.memory_analysis().temp_size_in_bytes
+             for c in (compiled, compile_for(nb // 2))]
+    assert abs(temps[0] - temps[1]) < (nb // 2) * bs * Hkv * Dh * 2, temps
